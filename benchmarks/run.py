@@ -30,6 +30,7 @@ from benchmarks import (bench_graph, bench_injected_vs_local,
                         bench_roofline, bench_serving, bench_stashing,
                         bench_tail_latency, bench_wfe)
 from benchmarks.common import write_bench_json
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = (
     ("fig5_6", bench_mailbox_overhead),
@@ -49,6 +50,7 @@ def main() -> None:
     p.add_argument("--only", default=None,
                    help="run only modules whose tag contains this substring")
     args = p.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failed = []

@@ -27,8 +27,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro import compat
-
 PyTree = Any
 
 _COMMIT = "COMMIT"
@@ -49,7 +47,7 @@ def _path_str(path) -> str:
 
 
 def flatten_with_paths(tree: PyTree) -> Tuple[List[Tuple[str, Any]], Any]:
-    leaves, treedef = compat.tree_flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     return [(_path_str(p), leaf) for p, leaf in leaves], treedef
 
 

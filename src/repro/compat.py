@@ -1,160 +1,22 @@
-"""JAX version-portability shims — the repo's single point of contact with
-version-dependent JAX APIs.
+"""Mesh construction — the one place the repo builds a ``jax.sharding.Mesh``.
 
-The codebase targets the modern (jax >= 0.6) public surface; this module
-backfills it on the 0.4.x line actually installed in the container, so that
-"the repo imports" is a tested contract rather than an accident of the
-installed JAX version.  Covered deltas:
-
-  * ``shard_map`` — moved to top-level ``jax.shard_map`` in 0.6 and renamed
-    its replication-check kwarg ``check_rep`` -> ``check_vma``; on 0.4.x the
-    implementation lives in ``jax.experimental.shard_map``.
-  * ``make_mesh`` — grew an ``axis_types=`` kwarg in 0.6 (with
-    ``jax.sharding.AxisType``, which does not exist on 0.4.x).  The shim
-    accepts and silently drops ``axis_types`` on old versions, where every
-    mesh axis behaves like the modern ``Auto`` default anyway.
-  * ``AbstractMesh`` — the two-argument ``AbstractMesh(sizes, names)``
-    constructor is 0.6+; 0.4.x takes one tuple of ``(name, size)`` pairs.
-
-Policy: supported JAX versions are 0.4.35 – 0.7.x.  Every ``shard_map`` /
-``make_mesh`` / ``AbstractMesh`` call site in ``src/`` and ``tests/`` must go
-through this module (or through ``core.transport.sharded_call``, which wraps
-it); ``tests/test_transport.py`` enforces the grep-level contract.
+``jax.make_mesh`` gives **Explicit** axes by default. The repo's sharding
+code is written for **Auto** axes: ``with_sharding_constraint`` inside the
+steps, pjit ``in_shardings``/``out_shardings`` and plain ``jnp`` indexing
+under ``with mesh:`` all raise on Explicit axes (``ShardingTypeError``).
+So every mesh in ``src/`` and ``tests/`` is built here, with Auto axes.
 """
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Optional, Sequence
 
 import jax
-
-
-def _version_tuple(v: str) -> Tuple[int, ...]:
-    parts = []
-    for piece in v.split(".")[:3]:
-        digits = "".join(ch for ch in piece if ch.isdigit())
-        parts.append(int(digits or 0))
-    return tuple(parts)
-
-
-JAX_VERSION: Tuple[int, ...] = _version_tuple(jax.__version__)
-
-#: True when this install exposes the modern top-level ``jax.shard_map``.
-HAS_TOPLEVEL_SHARD_MAP: bool = hasattr(jax, "shard_map")
-
-#: ``jax.sharding.AxisType`` on >= 0.6, else None (0.4.x has no axis types).
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-if not HAS_TOPLEVEL_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``jax.shard_map``.
-
-    Mirrors the modern keyword surface (``check_vma``); on 0.4.x the flag is
-    forwarded as ``check_rep``, which guards the same per-output replication
-    analysis under its old name.
-    """
-    if HAS_TOPLEVEL_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma)
-
-
-_HAS_LAX_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, inside a shard_map body.
-
-    ``jax.lax.axis_size`` is 0.6+; on 0.4.x ``psum(1, axis)`` of a Python
-    literal constant-folds to the same static int (the classic pmap idiom).
-    """
-    if _HAS_LAX_AXIS_SIZE:
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# pallas (imported lazily — kernels are the only consumers)
-# ---------------------------------------------------------------------------
-
-def pallas_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (0.6+) / ``TPUCompilerParams`` (0.4.x).
-
-    Constructor kwargs the installed version doesn't know (e.g.
-    ``has_side_effects`` on 0.4.x, where mosaic has no such knob) are
-    dropped rather than erroring — they are compile-time hints, not
-    semantics the interpret-mode tests depend on.
-    """
-    import dataclasses as _dc
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    fields = {f.name for f in _dc.fields(cls)}
-    return cls(**{k: v for k, v in kwargs.items() if k in fields})
-
-
-def has_pallas_tpu_interpret() -> bool:
-    """True when the TPU-semantics Pallas interpreter (``InterpretParams``)
-    exists — required to interpret kernels with *remote* DMAs on CPU."""
-    from jax.experimental.pallas import tpu as pltpu
-    return hasattr(pltpu, "InterpretParams")
-
-
-def pallas_tpu_interpret_mode():
-    """Value for ``pallas_call(interpret=...)`` requesting TPU-semantics
-    interpretation: ``InterpretParams()`` on 0.6+, plain ``True`` (the
-    generic interpreter) on 0.4.x.  Callers whose kernels issue remote DMAs
-    must gate on :func:`has_pallas_tpu_interpret` first."""
-    from jax.experimental.pallas import tpu as pltpu
-    if hasattr(pltpu, "InterpretParams"):
-        return pltpu.InterpretParams()
-    return True
-
-
-def tree_flatten_with_path(tree):
-    """``jax.tree.flatten_with_path`` (0.5+) / ``jax.tree_util`` (0.4.x)."""
-    if hasattr(jax.tree, "flatten_with_path"):
-        return jax.tree.flatten_with_path(tree)
-    return jax.tree_util.tree_flatten_with_path(tree)
-
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
-              devices: Optional[Sequence[Any]] = None,
-              axis_types: Optional[Sequence[Any]] = None) -> jax.sharding.Mesh:
-    """Version-portable ``jax.make_mesh``.
-
-    ``axis_types`` (a tuple of ``AxisType`` on modern JAX, or None for the
-    all-``Auto`` default) is dropped on 0.4.x, whose meshes carry no axis
-    types — equivalent to all-``Auto``.
-    """
-    kwargs: dict = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is not None and _MAKE_MESH_HAS_AXIS_TYPES:
-        kwargs["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def auto_axis_types(n_axes: int):
-    """``axis_types`` tuple for an all-``Auto`` mesh, or None on 0.4.x."""
-    if AxisType is None:
-        return None
-    return (AxisType.Auto,) * n_axes
-
-
-def abstract_mesh(axis_shapes: Sequence[int],
-                  axis_names: Sequence[str]) -> "jax.sharding.AbstractMesh":
-    """Version-portable ``AbstractMesh(sizes, names)`` (device-free mesh)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        # 0.4.x constructor: one tuple of (axis_name, size) pairs
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+              devices: Optional[Sequence[Any]] = None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_shapes),
+                         devices=devices)

@@ -34,8 +34,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
 from jax.sharding import Mesh
 
 from repro.configs.base import MoEConfig
@@ -76,7 +74,7 @@ def _scatter_buckets(xf, slot, n_slots):
 
 def _sp_slice(xf: jax.Array, tp_axis: str) -> Tuple[jax.Array, int]:
     """Sequence/token-parallel slice of the (replicated) token block."""
-    tp = compat.axis_size(tp_axis)
+    tp = jax.lax.axis_size(tp_axis)
     rank = jax.lax.axis_index(tp_axis)
     n = xf.shape[0]
     n_loc = n // tp
@@ -111,7 +109,7 @@ def _aux_pmean(aux: jax.Array, tp_axis: str,
 def _local_body(router, wg, wu, wd, shared, xf, tm=None, *, m: MoEConfig,
                 act: str, tp_axis: str, dp_axes: Tuple[str, ...]):
     """Local Function mode: token all-to-all to resident experts."""
-    tp = compat.axis_size(tp_axis)
+    tp = jax.lax.axis_size(tp_axis)
     e_loc = wg.shape[0]                       # experts resident on this rank
     e = m.num_experts
     xloc, n_loc = _sp_slice(xf, tp_axis)
@@ -175,7 +173,7 @@ def _tp_body(router, wg, wu, wd, shared, xf, tm=None, *, m: MoEConfig,
              act: str, tp_axis: str, dp_axes: Tuple[str, ...]):
     """Fallback: full token set everywhere; each rank serves only its
     resident experts; partial results combined with psum."""
-    tp = compat.axis_size(tp_axis)
+    tp = jax.lax.axis_size(tp_axis)
     rank = jax.lax.axis_index(tp_axis)
     e_loc = wg.shape[0]
     e = m.num_experts

@@ -22,7 +22,6 @@ from typing import Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core.message import FrameSpec
 
 
@@ -78,7 +77,7 @@ def ring_put(frame_block: jax.Array, axis_name: str, shift: int = 1) -> jax.Arra
     Must run inside shard_map. frame_block: (..., W) frames this device
     sends; returns the frames that LANDED here from the neighbor.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(frame_block, axis_name, perm)
 

@@ -9,10 +9,9 @@ MoE jam transport (``core.dispatch``), the Pallas mailbox ring
 (``runtime.pipeline_parallel``) all build their device programs with
 ``sharded_call`` instead of calling ``shard_map`` directly.  One seam buys:
 
-  1. one place where the JAX-version compat shim applies (``repro.compat``),
-  2. uniform telemetry — which transports were built, what auto-mode decided,
+  1. uniform telemetry — which transports were built, what auto-mode decided,
      how often the injected-mode weight-gather cache hit,
-  3. one place to evolve mesh/replication semantics later.
+  2. one place to evolve mesh/replication semantics later.
 """
 from __future__ import annotations
 
@@ -23,7 +22,6 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 
-from repro import compat
 from repro.configs.base import MoEConfig
 from repro.core import costmodel
 from repro.core.costmodel import TransportEstimate
@@ -83,15 +81,15 @@ def reset_telemetry() -> TransportTelemetry:
 def sharded_call(body: Callable, mesh, in_specs, out_specs, *,
                  label: str = "transport",
                  check_replication: bool = False) -> Callable:
-    """Build a shard_map'd callable through the compat shim.
+    """Build a shard_map'd callable — the repo's one ``jax.shard_map``.
 
     ``label`` names the call site in telemetry.  ``check_replication`` maps
-    to ``check_vma`` (modern) / ``check_rep`` (0.4.x); the repo's transports
-    hand-manage replication, so it defaults off.
+    to ``check_vma``; the repo's transports hand-manage replication, so it
+    defaults off.
     """
     with _LOCK:
         _TELEMETRY.record_build(label)
-    return compat.shard_map(body, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                             out_specs=out_specs,
                             check_vma=check_replication)
 

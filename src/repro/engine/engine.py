@@ -450,6 +450,12 @@ class Engine:
                                 state=self.params,
                                 placement=placement or self.placement)
 
+    def lower_step(self):
+        """The tick's compiled step, lowered for its abstract inputs (no
+        weights needed): ``.compile().as_text()`` shows what the chip runs
+        — e.g. whether the paged kernel is a Mosaic ``tpu_custom_call``."""
+        return self._jit_step.lower(*self.bundle.abstract_inputs)
+
     # -- placement resolution (the cost-model side of placement="auto") ----
 
     def _params_nbytes(self) -> int:
@@ -524,11 +530,21 @@ class Engine:
     # ------------------------------------------------------------------
 
     def load_params(self, params: Optional[PyTree] = None) -> None:
-        """Install model weights (init randomly when none given)."""
+        """Install model weights (init randomly from ``run.seed`` when none
+        given). A given tree is placed onto this engine's own parameter
+        shardings: a tree shared by replicas on different devices lands on
+        each replica's device, not on whichever device built it."""
         if params is None:
-            init = jax.jit(lambda k: model_lib.init_params(self.cfg, k)[0],
-                           out_shardings=self.pshard)
+            # in the dtype the step is built for (serving deploys bf16,
+            # runtime.steps.sharding_ctx): the same values the step would
+            # cast an f32 tree to, at half the HBM
+            dtype = jax.tree.leaves(self.params_shapes)[0].dtype
+            init = jax.jit(lambda k: model_lib.init_params(
+                self.cfg, k, param_dtype=dtype)[0],
+                out_shardings=self.pshard)
             params = init(jax.random.PRNGKey(self.run.seed))
+        else:
+            params = jax.device_put(params, self.pshard)
         self.params = params
         self._params_nbytes_memo = None
         self.cache = self._fresh_cache()

@@ -37,7 +37,6 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 
-from repro import compat
 
 # Mirrors core.message constants (kept literal: kernels are dependency-free).
 SIG_MAGIC = 0x516A_22
@@ -53,7 +52,7 @@ def _mailbox_kernel(frames_ref, out_ref, spins_ref, sums_ref, send_sem,
                     stash: bool, handler: Optional[str], sig_off: int,
                     usr_off: int, payload_words: int, n_frames: int):
     my = jax.lax.axis_index(axis_name)
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     dst = jax.lax.rem(my + shift, n)
     rdma = pltpu.make_async_remote_copy(
         src_ref=frames_ref, dst_ref=out_ref,
@@ -124,20 +123,14 @@ def mailbox_put_pallas(
     # Remote DMAs need the TPU-semantics interpreter (InterpretParams), not
     # the generic Pallas interpreter — the latter cannot discharge
     # mesh-logical device ids.
-    if interpret and not compat.has_pallas_tpu_interpret():
-        raise NotImplementedError(
-            "mailbox_put_pallas needs the TPU-semantics Pallas interpreter "
-            "(jax >= 0.6) to run off-TPU; this jax "
-            f"({jax.__version__}) has no pltpu.InterpretParams. Use the "
-            "core.mailbox shard_map reference transport instead.")
-    interp = compat.pallas_tpu_interpret_mode() if interpret else False
+    interp = pltpu.InterpretParams() if interpret else False
     arrivals, spins, sums = pl.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec(memory_space=mem)],
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             has_side_effects=True, collective_id=7),
         interpret=interp,
     )(frames)
@@ -204,9 +197,8 @@ def _indirect_put_kernel(got_ref, frames_ref, table_ref, heap_ref,
         key = frames_ref[i, usr_off]
         idx = jnp.remainder(jnp.remainder(key, slots) + got_base, slots)
         data = frames_ref[i, usr_off + 1:usr_off + payload_words]
-        pl.store(table_out, (pl.ds(idx, 1), slice(None)),
-                 jnp.stack([key, idx])[None, :])
-        pl.store(heap_out, (pl.ds(idx, 1), slice(None)), data[None, :])
+        table_out[pl.ds(idx, 1), :] = jnp.stack([key, idx])[None, :]
+        heap_out[pl.ds(idx, 1), :] = data[None, :]
         return 0
 
     jax.lax.fori_loop(0, n_frames, body, 0)

@@ -29,8 +29,6 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def _act(h, act: str):
     if act == "silu":
@@ -86,7 +84,7 @@ def moe_jam_ffn_pallas(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
         out_specs=pl.BlockSpec((1, bc, d), lambda e_, c_, f_: (e_, c_, 0)),
         out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32)],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_gate, w_up, w_down)

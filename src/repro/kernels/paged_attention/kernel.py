@@ -7,20 +7,30 @@ TPU analogue of the paper's §VII-B stash path: injected state (the KV pool)
 is consumed in cache-adjacent fast memory (VMEM) where it lands, instead of
 bouncing through a dense DRAM copy first (which is what ``ref.py`` does).
 
-Grid: ``(B, K, M)`` — request slot x kv head x kv block, kv innermost and
-*arbitrary* so the (m, l, acc) running statistics live in VMEM scratch
-across kv steps.
+Grid: ``(B, M)`` — request slot x kv block, kv innermost and *arbitrary*
+so the per-head (m, l, acc) running statistics live in VMEM scratch across
+kv steps. One grid step fetches a whole pool block, all K kv heads, and
+loops over the heads in the body.
 
 Operands (``PrefetchScalarGridSpec``, scalars prefetched to SMEM so the
 DMA engine can compute pool addresses before the body runs):
   scalar  block_tables (B, M) int32   pool block ids, -1 = unallocated
   scalar  starts       (B,)  int32    absolute position of column 0
   scalar  seq_end      (B,)  int32    tokens resident after this step
-  q   (1, 1, G*C, D) per (b, k, ·)    all C chunk columns x G group heads
-  k   (1, bs, 1, D)  per (·, k, j)    pool block ``tables[b, min(j, last)]``
-  v   (1, bs, 1, D)  same
-  out (1, 1, G*C, D) per (b, k, ·)    written at the last kv step
-  scratch: m (G*C, 1) f32, l (G*C, 1) f32, acc (G*C, D) f32
+  q   (1, K, G*C, D) per (b, ·)       all C chunk columns x H heads
+  k   (1, bs, K, D)  per (·, j)       pool block ``tables[b, min(j, last)]``
+  v   (1, bs, K, D)  same
+  out (1, K, G*C, D) per (b, ·)       written at the last kv step
+  scratch: m (K, G*C, 1) f32, l (K, G*C, 1) f32, acc (K, G*C, D) f32
+
+Block layout: the TPU lowering needs the last two block dims divisible by
+(8, 128) or equal to the array's. A per-head block ``(1, bs, 1, D)`` over
+the ``(N, bs, K, D)`` pool breaks that (1 against K), so the kernel reads
+``(1, bs, K, D)``: one contiguous ``bs * K * D`` DMA per block for k and
+one for v (16 KiB each in bf16 at bs=16, K=8, D=64). A head-major pool
+``(N, K, bs, D)`` with ``(1, 1, bs, D)`` blocks reads the same bytes as K
+DMAs of ``bs * D`` each, but would change the pool layout for every writer,
+reader and sharding rule of the cache.
 
 Early exit: the kv index map clamps ``j`` to the request's last live block
 (``ceil(seq_end / bs) - 1``), so dead grid steps re-address the block the
@@ -40,8 +50,6 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 
-from repro import compat
-
 NEG_INF = -2.0 ** 30
 
 
@@ -49,9 +57,9 @@ def _paged_kernel(tables_ref, starts_ref, seq_end_ref, q_ref, k_ref, v_ref,
                   o_ref, m_ref, l_ref, acc_ref, *, bs: int, chunk: int,
                   window: Optional[int], scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    gc = q_ref.shape[2]                           # G * C rows
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    kv_heads, gc = q_ref.shape[1], q_ref.shape[2]   # K, G * C rows
 
     @pl.when(j == 0)
     def _init():
@@ -70,14 +78,8 @@ def _paged_kernel(tables_ref, starts_ref, seq_end_ref, q_ref, k_ref, v_ref,
 
     @pl.when(visible)
     def _block():
-        q = q_ref[0, 0]                           # (G*C, D)
-        k = k_ref[0, :, 0, :]                     # (bs, D)
-        v = v_ref[0, :, 0, :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (G*C, bs)
-
-        # row r = g * C + c serves chunk column c = r % C of group head g
+        # row r = g * C + c serves chunk column c = r % C of group head g;
+        # the mask is the same for every kv head
         q_pos = start + jax.lax.rem(
             jax.lax.broadcasted_iota(jnp.int32, (gc, bs), 0), chunk)
         k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (gc, bs), 1)
@@ -86,23 +88,31 @@ def _paged_kernel(tables_ref, starts_ref, seq_end_ref, q_ref, k_ref, v_ref,
         if window is not None:
             mask &= rel < window
         mask &= k_pos < seq_end                   # stale rows of reused blocks
-        s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]                       # (G*C, 1)
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        m_ref[...] = m_new
-        acc_ref[...] = (acc_ref[...] * alpha
-                        + jax.lax.dot_general(
-                            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        for h in range(kv_heads):                 # static: K is small
+            q = q_ref[0, h]                       # (G*C, D)
+            k = k_ref[0, :, h, :]                 # (bs, D)
+            v = v_ref[0, :, h, :]
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (G*C, bs)
+            s = jnp.where(mask, s, NEG_INF)
+
+            m_prev = m_ref[h]                     # (G*C, 1)
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + p.sum(-1, keepdims=True)
+            m_ref[h] = m_new
+            acc_ref[h] = (acc_ref[h] * alpha
+                          + jax.lax.dot_general(
+                              p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32))
 
     @pl.when(j == nj - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(
@@ -134,10 +144,10 @@ def paged_attention_pallas(
     starts = starts.astype(jnp.int32)
     seq_end = starts + n_valid.astype(jnp.int32)
 
-    def q_map(b, h, j, tables, starts, seq_end):
-        return (b, h, 0, 0)
+    def q_map(b, j, tables, starts, seq_end):
+        return (b, 0, 0, 0)
 
-    def kv_map(b, h, j, tables, starts, seq_end):
+    def kv_map(b, j, tables, starts, seq_end):
         # clamp dead steps to the nearest live block (same address => the
         # pipeline skips the copy) and unallocated slots (-1) to block 0
         # (their positions are >= seq_end, masked in-kernel). Dead means
@@ -150,21 +160,21 @@ def paged_attention_pallas(
         if window is not None:
             lo = jnp.clip((starts[b] - (window - 1)) // bs, 0, last)
         blk = tables[b, jnp.clip(j, lo, last)]
-        return (jnp.maximum(blk, 0), 0, h, 0)
+        return (jnp.maximum(blk, 0), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, K, M),
+        grid=(B, M),
         in_specs=[
-            pl.BlockSpec((1, 1, G * C, D), q_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
-            pl.BlockSpec((1, bs, 1, D), kv_map),
+            pl.BlockSpec((1, K, G * C, D), q_map),
+            pl.BlockSpec((1, bs, K, D), kv_map),
+            pl.BlockSpec((1, bs, K, D), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, G * C, D), q_map),
+        out_specs=pl.BlockSpec((1, K, G * C, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G * C, 1), jnp.float32),
-            pltpu.VMEM((G * C, 1), jnp.float32),
-            pltpu.VMEM((G * C, D), jnp.float32),
+            pltpu.VMEM((K, G * C, 1), jnp.float32),
+            pltpu.VMEM((K, G * C, 1), jnp.float32),
+            pltpu.VMEM((K, G * C, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -172,8 +182,8 @@ def paged_attention_pallas(
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G * C, D), q.dtype),
-        compiler_params=compat.pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tables, starts, seq_end, qg, k_pool, v_pool)
     return (out.reshape(B, K, G, C, D).transpose(0, 3, 1, 2, 4)

@@ -3,13 +3,11 @@
 ``paged_attention`` mirrors ``ref.paged_attention_ref``'s signature so the
 two are drop-in interchangeable in ``models.attention.gqa_paged_attention``.
 
-``resolve_kernel`` implements the ``kernel="auto"`` policy (ISSUE 4): the
-Pallas path is selected when it can run with TPU semantics — a real TPU
-backend, or the TPU-semantics Pallas interpreter (``pltpu.InterpretParams``,
-jax >= 0.6). Anywhere else ``auto`` serves the fp-exact ``ref`` oracle; the
-kernel remains explicitly requestable (``kernel="pallas"``) and then runs
-under the generic Pallas interpreter off-TPU — that is how the CPU
-differential tests drive it.
+``resolve_kernel`` implements the ``kernel="auto"`` policy: the Pallas
+kernel, compiled on a TPU and run under the TPU-semantics Pallas
+interpreter (``pltpu.InterpretParams``) elsewhere — that is how the CPU
+differential tests drive it. The ``ref`` oracle stays explicitly
+requestable (``kernel="ref"``).
 
 ``make_sharded_paged_attention`` (ISSUE 7) is the kernel's multi-device
 lowering: a ``core.transport.sharded_call``-wrapped ``paged_attention``
@@ -35,11 +33,11 @@ from functools import partial
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
+import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.kernels.paged_attention.ref import paged_attention_ref
 
@@ -51,36 +49,26 @@ def _on_tpu() -> bool:
 
 
 def resolve_kernel(kind: str, n_devices: int = 1) -> str:
-    """Resolve ``"auto"`` to the kernel that should serve on this backend.
+    """Resolve ``"auto"`` to the kernel that serves: always ``"pallas"``.
 
-    ``auto`` needs TPU semantics — a real TPU, or the TPU-semantics Pallas
-    interpreter. Device count no longer matters (ISSUE 7): on >1-device
-    meshes the kernel lowers through ``make_sharded_paged_attention``
-    (kv heads over tp, request rows over dp), so ``auto`` picks pallas on
-    any mesh whenever TPU semantics are available.
-
-    Note the ISSUE-4 policy deliberately includes the TPU-semantics
-    *interpreter* in ``auto``: semantics-faithful, but Python-interpreted —
-    far slower than the XLA-compiled ref path for real CPU serving on
-    jax >= 0.6. CPU deployments that care about throughput should pass
-    ``--paged-kernel ref`` explicitly (docs/serving.md).
+    On a TPU the kernel compiles through Mosaic; elsewhere it runs under
+    the TPU-semantics Pallas interpreter (``pltpu.InterpretParams``) —
+    semantics-faithful, but Python-interpreted, so CPU deployments that
+    care about throughput pass ``--paged-kernel ref`` explicitly
+    (docs/serving.md). Device count does not matter: on >1-device meshes
+    the kernel lowers through ``make_sharded_paged_attention`` (kv heads
+    over tp, request rows over dp).
     """
     if kind not in KERNEL_KINDS:
         raise ValueError(f"kernel must be one of {KERNEL_KINDS}, got {kind!r}")
-    if kind != "auto":
-        return kind
     del n_devices  # the sharded lowering serves every device count
-    return "pallas" if (_on_tpu() or compat.has_pallas_tpu_interpret()) \
-        else "ref"
+    return "pallas" if kind == "auto" else kind
 
 
 def _resolve_interpret(interpret: bool | None) -> object:
-    """None => auto: interpret off-TPU, preferring the TPU-semantics
-    interpreter when the jax version has one."""
+    """None => auto: the TPU-semantics interpreter off-TPU, Mosaic on TPU."""
     interp: object = (not _on_tpu()) if interpret is None else interpret
-    if interp:
-        interp = compat.pallas_tpu_interpret_mode()
-    return interp
+    return pltpu.InterpretParams() if interp else False
 
 
 @partial(jax.jit, static_argnames=("block_size", "window", "scale",
@@ -91,8 +79,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     window: Optional[int] = None,
                     scale: Optional[float] = None,
                     interpret: bool | None = None) -> jax.Array:
-    """(B,C,H,D) x pool -> (B,C,H,D). interpret=None => auto (CPU interprets,
-    preferring the TPU-semantics interpreter when the jax version has it)."""
+    """(B,C,H,D) x pool -> (B,C,H,D). interpret=None => auto (off-TPU runs
+    the TPU-semantics interpreter)."""
     return paged_attention_pallas(
         q, k_pool, v_pool, block_tables, starts, n_valid,
         block_size=block_size, window=window, scale=scale,

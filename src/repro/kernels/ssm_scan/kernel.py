@@ -25,8 +25,6 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 
-from repro import compat
-
 
 def _ssm_scan_kernel(dt_ref, b_ref, c_ref, x_ref, a_ref, h0_ref,
                      y_ref, hout_ref, h_ref, *, tc: int):
@@ -92,7 +90,7 @@ def ssm_scan_pallas(dt: jax.Array, b: jax.Array, c: jax.Array, x: jax.Array,
             jax.ShapeDtypeStruct((B, I, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((I, N), jnp.float32)],
-        compiler_params=compat.pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(dt, b, c, x, a, h0)

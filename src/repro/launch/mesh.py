@@ -11,7 +11,7 @@ on it).
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
 
@@ -37,11 +37,14 @@ def make_production_mesh(*, multi_pod: bool = False,
     return compat.make_mesh(shape, axes, devices=devices[:n])
 
 
-def make_test_mesh(shape: Tuple[int, ...] = (1, 1),
-                   axes: Tuple[str, ...] = ("data", "model")) -> jax.sharding.Mesh:
-    """Small mesh over however many local devices exist (tests/examples)."""
+def make_local_mesh(shape: Tuple[int, ...] = (1, 1),
+                    axes: Tuple[str, ...] = ("data", "model"), *,
+                    devices: Optional[Sequence[Any]] = None
+                    ) -> jax.sharding.Mesh:
+    """Mesh over the first ``prod(shape)`` of ``devices`` (default: the
+    devices present) — the serving launchers' mesh."""
     n = math.prod(shape)
-    devices = jax.devices()
+    devices = list(jax.devices() if devices is None else devices)
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
     return compat.make_mesh(shape, axes, devices=devices[:n])

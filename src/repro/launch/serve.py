@@ -1,5 +1,12 @@
 """Serving launcher: the unified engine with pluggable schedulers.
 
+Serves on a mesh built from the devices present (``--mesh``, 1x1 by
+default). ``--smoke`` swaps in the arch's reduced CPU-test config; without
+it the published config serves, e.g. llama3.2-1b at full width on one TPU:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b \
+      --cache paged --slots 8 --max-len 544 --prompt-len 256 --max-new 32
+
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --smoke \
       --requests 12 --slots 4 --max-new 16
 
@@ -25,22 +32,67 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from typing import Optional, Tuple
 
 import jax
 import numpy as np
 
-from repro import compat
-from repro.configs.base import SHAPES, RunConfig, ShardingConfig
+from repro.configs.base import SHAPES, ModelConfig, RunConfig, ShardingConfig
 from repro.configs.registry import (ARCHS, default_cache_backend, get_config,
                                     get_smoke)
 from repro.engine import Engine, Request
-from repro.launch.mesh import make_production_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh
+
+DEFAULT_CHUNK = 8       # paged/recurrent prefill tokens per request per tick
+
+# serving keeps whole weights per tensor shard (no FSDP) and the paged pool
+# shards kv heads over the tensor axis, never the sequence
+SERVE_SHARDING = ShardingConfig(fsdp_params=False, seq_axis=None)
+
+
+def parse_mesh(spec: Optional[str]) -> Tuple[int, int]:
+    """``"DPxTP"`` (e.g. ``"2x2"``) -> (dp, tp); None -> (1, 1)."""
+    if not spec:
+        return 1, 1
+    try:
+        dp, tp = (int(t) for t in spec.lower().split("x"))
+    except ValueError:
+        raise ValueError(f"--mesh wants DPxTP (e.g. 2x2), got {spec!r}") from None
+    return dp, tp
+
+
+def build_engine(cfg: ModelConfig, mesh: jax.sharding.Mesh, *, cache: str,
+                 slots: int, max_len: int, num_blocks: int = 0,
+                 block_size: int = 16, chunk: int = DEFAULT_CHUNK,
+                 scheduler="fifo", kernel: str = "auto", seed: int = 0,
+                 engine_id: Optional[str] = None,
+                 placement: str = "local") -> Engine:
+    """One serving ``Engine`` on ``mesh`` — the construction shared by
+    the launchers and ``chip_smoke.py``. ``num_blocks=0`` sizes the paged
+    pool at half the contiguous budget, floored at one ``max_len``
+    sequence (the engine rejects anything smaller). Weights are not
+    installed: call ``load_params``/``inject_params``."""
+    run = RunConfig(model=cfg, shape=SHAPES["decode_32k"],
+                    sharding=SERVE_SHARDING, seed=seed)
+    kw = dict(slots=slots, max_len=max_len, scheduler=scheduler,
+              engine_id=engine_id, placement=placement)
+    if cache == "paged":
+        max_blocks_per_seq = -(-max_len // block_size)
+        kw.update(num_blocks=num_blocks or max(
+                      max_blocks_per_seq, (slots * max_len // 2) // block_size),
+                  block_size=block_size, chunk=chunk, kernel=kernel)
+    elif cache == "recurrent":
+        kw.update(chunk=chunk)
+    return Engine(cfg, run, mesh, cache=cache, **kw)
 
 
 def main() -> None:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", choices=sorted(ARCHS), required=True)
-    p.add_argument("--smoke", action="store_true")
+    p.add_argument("--smoke", action="store_true",
+                   help="serve the arch's reduced CPU-test config instead "
+                        "of its published widths")
     p.add_argument("--slots", type=int, default=4)
     p.add_argument("--max-len", type=int, default=256)
     p.add_argument("--requests", type=int, default=8)
@@ -68,26 +120,25 @@ def main() -> None:
                         "worth of tokens — half the contiguous budget)")
     p.add_argument("--block-size", type=int, default=16,
                    help="paged: tokens per block")
-    p.add_argument("--chunk", type=int, default=8,
+    p.add_argument("--chunk", type=int, default=DEFAULT_CHUNK,
                    help="paged: prefill tokens per request per tick")
     p.add_argument("--paged-kernel", choices=("auto", "pallas", "ref"),
                    default="auto",
                    help="paged attention path: the stash-resident Pallas "
                         "block-table kernel (single- or multi-device — it "
-                        "lowers through shard_map on meshes), the "
-                        "gather-then-dense reference, or auto (pallas "
-                        "wherever TPU semantics are available, any device "
-                        "count)")
+                        "lowers through shard_map on meshes; interpreted "
+                        "off-TPU), the gather-then-dense reference, or "
+                        "auto (pallas)")
     p.add_argument("--mesh", default=None, metavar="DPxTP",
-                   help="smoke-mode mesh shape, e.g. 2x2 or 1x4 (axes "
-                        "data x model; needs dp*tp local devices — on CPU "
-                        "set XLA_FLAGS=--xla_force_host_platform_device_"
-                        "count=N). Default: 1x1. Ignored without --smoke "
-                        "(production uses make_production_mesh)")
+                   help="mesh shape over the devices present, e.g. 2x2 or "
+                        "1x4 (axes data x model; needs dp*tp devices — on "
+                        "CPU set XLA_FLAGS=--xla_force_host_platform_device_"
+                        "count=N). Default: 1x1")
     p.add_argument("--metrics-json", action="store_true",
                    help="print the final Engine.metrics() dict as JSON")
     args = p.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.is_encoder:
         raise SystemExit(f"{args.arch} is encoder-only: no decode path")
@@ -107,48 +158,25 @@ def main() -> None:
     if cache not in ("paged",) and (args.blocks or args.block_size != 16):
         p.error(f"--blocks/--block-size configure the paged pool and have "
                 f"no effect with --cache {cache}")
-    if args.smoke:
-        dp, tp = 1, 1
-        if args.mesh:
-            try:
-                dp, tp = (int(t) for t in args.mesh.lower().split("x"))
-            except ValueError:
-                p.error(f"--mesh wants DPxTP (e.g. 2x2), got {args.mesh!r}")
-            if dp * tp > len(jax.devices()):
-                p.error(f"--mesh {args.mesh} needs {dp * tp} devices, have "
-                        f"{len(jax.devices())} (on CPU: XLA_FLAGS="
-                        f"--xla_force_host_platform_device_count={dp * tp})")
-        mesh = compat.make_mesh((dp, tp), ("data", "model"))
-        sharding = ShardingConfig(fsdp_params=False, seq_axis=None)
-    else:
-        if args.mesh:
-            p.error("--mesh is smoke-only; production uses "
-                    "make_production_mesh()")
-        mesh = make_production_mesh()
-        sharding = ShardingConfig(fsdp_params=False, seq_axis="model")
-    run = RunConfig(model=cfg, shape=SHAPES["decode_32k"], sharding=sharding)
+    try:
+        dp, tp = parse_mesh(args.mesh)
+    except ValueError as e:
+        p.error(str(e))
+    if dp * tp > len(jax.devices()):
+        p.error(f"--mesh {args.mesh} needs {dp * tp} devices, have "
+                f"{len(jax.devices())} (on CPU: XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={dp * tp})")
+    mesh = make_local_mesh((dp, tp))
+    print(f"[serve] {cfg.name} on a {dp}x{tp} mesh of "
+          f"{jax.devices()[0].platform} devices")
 
     rng = np.random.default_rng(0)
     with mesh:
-        if cache == "paged":
-            # default: half the contiguous budget, floored at one full
-            # max_len sequence (the engine rejects anything smaller)
-            max_blocks_per_seq = -(-args.max_len // args.block_size)
-            num_blocks = args.blocks or max(
-                max_blocks_per_seq,
-                (args.slots * args.max_len // 2) // args.block_size)
-            engine = Engine(cfg, run, mesh, cache="paged", slots=args.slots,
-                            max_len=args.max_len, num_blocks=num_blocks,
-                            block_size=args.block_size, chunk=args.chunk,
-                            scheduler=args.scheduler,
-                            kernel=args.paged_kernel)
-        elif cache == "recurrent":
-            engine = Engine(cfg, run, mesh, cache="recurrent",
-                            slots=args.slots, max_len=args.max_len,
-                            chunk=args.chunk, scheduler=args.scheduler)
-        else:
-            engine = Engine(cfg, run, mesh, cache="slots", slots=args.slots,
-                            max_len=args.max_len, scheduler=args.scheduler)
+        engine = build_engine(
+            cfg, mesh, cache=cache, slots=args.slots, max_len=args.max_len,
+            num_blocks=args.blocks, block_size=args.block_size,
+            chunk=args.chunk, scheduler=args.scheduler,
+            kernel=args.paged_kernel)
         engine.load_params()
         t0 = time.perf_counter()
         handles = []

@@ -16,9 +16,14 @@
       --replicas llama3.2-1b:paged,llama3.2-1b:paged --migrate-after 3 \
       --fault-rate 0.3 --fault-seed 7 --kill-after 5
 
+Replica ``i`` serves on its own one-device mesh over ``jax.devices()[i %
+n]``: on a four-chip host, four replicas take one chip each. Without
+``--smoke`` each arch serves at its published widths.
+
 Each ``--replicas`` entry is ``arch:cache`` (cache one of
 paged/slots/recurrent/auto). Replicas of the same arch share one weight
-tree, installed via ``Engine.inject_params`` so every replica's params
+tree, installed via ``Engine.inject_params`` (which places it on each
+replica's own device) so every replica's params
 lease is warm and ``placement="auto"`` resolves to injected from the
 first tick — the router's cost model then places by load alone among
 warm replicas. Requests round through ``Router.submit`` with a priority
@@ -37,19 +42,21 @@ import json
 import sys
 import time
 
+import jax
 import numpy as np
 
-from repro import compat
-from repro.configs.base import SHAPES, RunConfig, ShardingConfig
 from repro.configs.registry import (ARCHS, default_cache_backend, get_config,
                                     get_smoke)
 from repro.cluster import (EngineFailedError, FaultInjector, FaultPlan,
                            MigrateOnOversubscription, MigrationFailedError,
                            Replica, RequestFailedError, Router)
-from repro.engine import Engine, Request
+from repro.engine import Request
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_local_mesh
+from repro.launch.serve import build_engine
 
 
-def _parse_replicas(spec: str, smoke: bool, error) -> list:
+def parse_replicas(spec: str, smoke: bool, error) -> list:
     out = []
     for i, item in enumerate(spec.split(",")):
         item = item.strip()
@@ -72,8 +79,36 @@ def _parse_replicas(spec: str, smoke: bool, error) -> list:
     return out
 
 
-def _run_phase(label, engines, specs, prompts, mesh, args, *,
-               injector=None, snapshot_every=0):
+def build_replicas(specs, args) -> list:
+    """One engine per ``(arch, cache, cfg)`` spec. Replica ``i`` gets its
+    own one-device mesh on ``jax.devices()[i % n]``; replicas of one arch
+    share one weight tree, which ``inject_params`` places onto each
+    replica's device. Returns ``[(engine, arch), ...]``."""
+    devices = jax.devices()
+    engines = []
+    params_by_arch: dict = {}
+    for i, (arch, cache, cfg) in enumerate(specs):
+        mesh = make_local_mesh((1, 1), devices=[devices[i % len(devices)]])
+        per_seq = -(-args.max_len // args.block_size)
+        with mesh:
+            eng = build_engine(
+                cfg, mesh, cache=cache, slots=args.slots,
+                max_len=args.max_len,
+                num_blocks=args.blocks or per_seq * args.slots,
+                block_size=args.block_size, chunk=args.chunk,
+                scheduler=args.scheduler, seed=args.seed,
+                placement="auto", engine_id=f"{arch}:{cache}#{i}")
+            # one weight tree per arch, injected into every replica of
+            # that arch: the rFaaS lease model — N warm executors, one
+            # shipped weight state
+            eng.inject_params(params_by_arch.get(arch))
+            params_by_arch.setdefault(arch, eng.params)
+        engines.append((eng, arch))
+    return engines
+
+
+def run_phase(label, engines, specs, prompts, args, *,
+              injector=None, snapshot_every=0):
     """Serve the fixed request set once on restarted engines behind a
     fresh router; returns (outputs per rid, failed rids, metrics, dt)."""
     for eng, _arch in engines:
@@ -87,51 +122,50 @@ def _run_phase(label, engines, specs, prompts, mesh, args, *,
     if injector is not None:
         injector.install(router)
 
-    with mesh:
-        handles = []
-        for rid in range(args.requests):
-            arch = specs[rid % len(specs)][0]
-            handles.append(router.submit(
-                Request(rid, prompts[rid], max_new_tokens=args.max_new,
-                        priority=rid % 3), model=arch))
+    handles = []
+    for rid in range(args.requests):
+        arch = specs[rid % len(specs)][0]
+        handles.append(router.submit(
+            Request(rid, prompts[rid], max_new_tokens=args.max_new,
+                    priority=rid % 3), model=arch))
 
-        t0 = time.perf_counter()
-        forced = None
-        ticks = 0
-        while router.pending() and ticks < 10_000:
-            router.tick()
-            ticks += 1
-            if (args.migrate_after and forced is None
-                    and ticks >= args.migrate_after):
-                # force one live handoff: the first unfinished request
-                # whose replica has a compatible live peer
-                for h in handles:
-                    if h.done or router.request_failure(h.rid) is not None:
-                        continue
-                    src = router._by_id[h.engine_id]
-                    if src.failed:
-                        continue
-                    # prefer a peer with headroom, but force the handoff
-                    # onto any compatible replica — it queues there
-                    dst = (router.best_target(src)
-                           or next(iter(router.compatible_targets(src)),
-                                   None))
-                    if dst is None:
-                        continue
-                    try:
-                        router.migrate(h.rid, dst.engine_id,
-                                       reason="forced")
-                    except (MigrationFailedError, EngineFailedError):
-                        continue        # rolled back / source died: retry
-                    forced = (h.rid, src.engine_id, dst.engine_id)
-                    break
-        dt = time.perf_counter() - t0
-        outputs, failed = {}, {}
-        for h in handles:
-            try:
-                outputs[h.rid] = list(h.result().out_tokens)
-            except RequestFailedError as err:
-                failed[h.rid] = str(err)
+    t0 = time.perf_counter()
+    forced = None
+    ticks = 0
+    while router.pending() and ticks < 10_000:
+        router.tick()
+        ticks += 1
+        if (args.migrate_after and forced is None
+                and ticks >= args.migrate_after):
+            # force one live handoff: the first unfinished request
+            # whose replica has a compatible live peer
+            for h in handles:
+                if h.done or router.request_failure(h.rid) is not None:
+                    continue
+                src = router._by_id[h.engine_id]
+                if src.failed:
+                    continue
+                # prefer a peer with headroom, but force the handoff
+                # onto any compatible replica — it queues there
+                dst = (router.best_target(src)
+                       or next(iter(router.compatible_targets(src)),
+                               None))
+                if dst is None:
+                    continue
+                try:
+                    router.migrate(h.rid, dst.engine_id,
+                                   reason="forced")
+                except (MigrationFailedError, EngineFailedError):
+                    continue        # rolled back / source died: retry
+                forced = (h.rid, src.engine_id, dst.engine_id)
+                break
+    dt = time.perf_counter() - t0
+    outputs, failed = {}, {}
+    for h in handles:
+        try:
+            outputs[h.rid] = list(h.result().out_tokens)
+        except RequestFailedError as err:
+            failed[h.rid] = str(err)
 
     m = router.metrics()
     undrained = router.pending()
@@ -163,12 +197,16 @@ def _run_phase(label, engines, specs, prompts, mesh, args, *,
     return outputs, failed, m, forced, undrained
 
 
-def main() -> None:
+def make_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--replicas", required=True,
                    help="comma list of arch:cache replica specs, e.g. "
                         "llama3.2-1b:paged,llama3.2-1b:paged")
-    p.add_argument("--smoke", action="store_true")
+    p.add_argument("--smoke", action="store_true",
+                   help="serve each arch's reduced CPU-test config instead "
+                        "of its published widths")
+    p.add_argument("--seed", type=int, default=0,
+                   help="seed of the random weights and prompts")
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--prompt-len", type=int, default=12)
     p.add_argument("--max-new", type=int, default=8)
@@ -202,49 +240,26 @@ def main() -> None:
                         "(router ticks; 0 = recompute-only failover)")
     p.add_argument("--metrics-json", action="store_true",
                    help="print the final cluster metrics() as JSON")
+    return p
+
+
+def main() -> None:
+    p = make_parser()
     args = p.parse_args()
 
-    if not args.smoke:
-        p.error("serve_cluster currently supports --smoke only "
-                "(production multi-host routing is ROADMAP work)")
-    specs = _parse_replicas(args.replicas, args.smoke, p.error)
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    sharding = ShardingConfig(fsdp_params=False, seq_axis=None)
+    enable_compile_cache()
+    specs = parse_replicas(args.replicas, args.smoke, p.error)
+    engines = build_replicas(specs, args)
 
-    # one weight tree per arch, injected into every replica of that arch:
-    # the rFaaS lease model — N warm executors, one shipped weight state
-    engines = []
-    params_by_arch: dict = {}
-    with mesh:
-        for i, (arch, cache, cfg) in enumerate(specs):
-            run = RunConfig(model=cfg, shape=SHAPES["decode_32k"],
-                            sharding=sharding)
-            kw = dict(slots=args.slots, max_len=args.max_len,
-                      scheduler=args.scheduler, placement="auto",
-                      engine_id=f"{arch}:{cache}#{i}")
-            if cache == "paged":
-                per_seq = -(-args.max_len // args.block_size)
-                kw.update(num_blocks=args.blocks or per_seq * args.slots,
-                          block_size=args.block_size, chunk=args.chunk)
-            elif cache == "recurrent":
-                kw.update(chunk=args.chunk)
-            eng = Engine(cfg, run, mesh, cache=cache, **kw)
-            if arch in params_by_arch:
-                eng.inject_params(params_by_arch[arch])
-            else:
-                eng.inject_params()
-                params_by_arch[arch] = eng.params
-            engines.append((eng, arch))
-
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     prompts = [rng.integers(0, specs[rid % len(specs)][2].vocab_size,
                             size=(args.prompt_len,)).astype(np.int32)
                for rid in range(args.requests)]
 
     chaos = args.fault_rate > 0 or args.kill_after > 0
-    outputs, failed, m, forced, undrained = _run_phase(
+    outputs, failed, m, forced, undrained = run_phase(
         "cluster" if not chaos else "baseline",
-        engines, specs, prompts, mesh, args)
+        engines, specs, prompts, args)
     ok = True
     if failed:
         print(f"[cluster] ERROR: requests failed without faults: {failed}",
@@ -259,8 +274,8 @@ def main() -> None:
             kill_at={engines[0][0].engine_id: args.kill_after}
             if args.kill_after else {})
         injector = FaultInjector(plan)
-        c_out, c_failed, m, forced, undrained = _run_phase(
-            "chaos", engines, specs, prompts, mesh, args,
+        c_out, c_failed, m, forced, undrained = run_phase(
+            "chaos", engines, specs, prompts, args,
             injector=injector, snapshot_every=args.snapshot_every)
         if c_failed:
             print(f"[chaos] ERROR: requests terminally failed: {c_failed}",

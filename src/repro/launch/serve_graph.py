@@ -33,6 +33,7 @@ from repro.configs.registry import ARCHS, get_config, get_smoke
 from repro.cluster import Replica, Router
 from repro.engine import Engine, Request
 from repro.fabric.graph import NgramDraft, SpeculativeDecoder
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _mk_engine(arch, mesh, engine_id, *, smoke, params=None, **kw):
@@ -68,6 +69,7 @@ def main(argv=None) -> int:
                    help="production configs instead of smoke configs")
     args = p.parse_args(argv)
     smoke = not args.full
+    enable_compile_cache()
 
     mesh = compat.make_mesh((1, 1), ("data", "model"))
     eng_kw = dict(slots=3, max_len=64, num_blocks=32, block_size=4,

@@ -33,6 +33,7 @@ import jax  # noqa: E402
 from repro import compat  # noqa: E402
 from repro.configs.base import SHAPES, OptimizerConfig, RunConfig, ShardingConfig  # noqa: E402
 from repro.configs.registry import ARCHS, get_config, get_smoke  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.runtime.trainer import Trainer, TrainerConfig  # noqa: E402
 
@@ -56,6 +57,7 @@ def main() -> None:
                    choices=("local", "injected", "auto"),
                    help="MoE jam transport override")
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if args.transport and cfg.moe is not None:

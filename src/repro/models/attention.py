@@ -310,8 +310,8 @@ def gqa_paged_attention(
     shared pool through the block table; scores then run either through the
     stash-resident Pallas kernel (``kernel="pallas"`` — live blocks stream
     pool->VMEM, the logical view never exists in HBM) or the gather-then-
-    dense oracle (``kernel="ref"``). ``"auto"`` picks pallas wherever TPU
-    semantics are available (``kernels.paged_attention.resolve_kernel``).
+    dense oracle (``kernel="ref"``). ``"auto"`` picks pallas
+    (``kernels.paged_attention.resolve_kernel``).
     ``kernel`` may also be a *callable* with ``paged_attention_ref``'s
     signature — that is how ``runtime.steps`` threads the shard_map'd
     multi-device lowering (``make_sharded_paged_attention``) down here

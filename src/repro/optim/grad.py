@@ -16,8 +16,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 PyTree = Any
 
 
@@ -67,7 +65,7 @@ def compressed_psum(grads: PyTree, axis: str | Tuple[str, ...],
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     n = 1
     for a in axes:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
 
     def one(g, e):
         gf = g.astype(jnp.float32) + (e if e is not None else 0.0)

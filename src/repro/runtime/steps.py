@@ -390,9 +390,13 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
     writes) — only the argmax reduction widens — which is what makes
     speculation bitwise output-neutral against target-only decode.
 
+    ``emit="logits"`` returns the float32 logits at every chunk column,
+    shape ``(slots, chunk, V)`` — what a comparison with a reference
+    forward reads (``repro.engine.teacher_forcing``).
+
     ``kernel`` selects the paged-attention path (``"pallas"``: the
     stash-resident block-table kernel; ``"ref"``: gather-then-dense;
-    ``"auto"``: pallas wherever TPU semantics are available). The resolved
+    ``"auto"``: pallas). The resolved
     choice lands in ``meta["paged_kernel"]``. On multi-device meshes the
     pallas path lowers through ``make_sharded_paged_attention`` — kv heads
     shard over the tensor axis (matching ``paged_cache_spec_tree``'s pool
@@ -406,8 +410,9 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
     steal expert capacity from real tokens (docs/fabric.md).
     """
     assert not cfg.is_encoder, "encoder-only arch has no decode step"
-    if emit not in ("last", "all"):
-        raise ValueError(f"emit must be 'last' or 'all', got {emit!r}")
+    if emit not in ("last", "all", "logits"):
+        raise ValueError(
+            f"emit must be 'last', 'all' or 'logits', got {emit!r}")
     rules, params_shapes, axes, pspecs, pshard = sharding_ctx(cfg, run, mesh)
     paged_kernel = paged_attention_lib.resolve_kernel(
         kernel, n_devices=mesh.devices.size)
@@ -431,6 +436,8 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
             cfg, params, tokens, cache=cache, paged=layout,
             paged_kernel=kernel_fn,
             moe_transport=transport, constrain=constrain)
+        if emit == "logits":
+            return logits, new_cache                     # (slots, chunk, V)
         if emit == "all":
             # verify wiring: greedy choice at every fed position
             next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -463,7 +470,8 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
         out_shardings=(rep, cache_shard),
         abstract_inputs=abstract,
         meta=dict(rules=rules, pspecs=pspecs, axes=axes,
-                  kind="paged_decode" if emit == "last" else "paged_verify",
+                  kind={"last": "paged_decode", "all": "paged_verify",
+                        "logits": "paged_logits"}[emit],
                   cache=cache_shapes, transport_log=transport_log,
                   fabric=fabric, block_size=block_size,
                   num_blocks=num_blocks, chunk=chunk, slots=slots,

@@ -29,7 +29,9 @@ def run_multidev(code: str, n_devices: int = 4, timeout: int = 240) -> str:
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_devices} "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.path.abspath(SRC) + os.pathsep + env.get("PYTHONPATH", "")
-    env.pop("JAX_PLATFORMS", None)
+    # the child runs on simulated CPU devices and must never try to load
+    # the TPU library (its lock belongs to whichever process took it)
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.Popen(
         [sys.executable, "-c", code], env=env, text=True,
         stdout=subprocess.PIPE, stderr=subprocess.PIPE)

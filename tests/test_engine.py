@@ -789,6 +789,38 @@ def test_inject_params_makes_auto_resolve_injected(setup):
     assert all(d.endswith("-> injected") for d in m["transport_decisions"])
 
 
+def test_inject_params_places_tree_on_engine_device(setup):
+    """A weight tree built on device 0 and injected into an engine whose
+    mesh is device 1 lands on device 1 — params and KV pool alike — so
+    router replicas sharing one tree each serve from their own device, and
+    both serve the same tokens."""
+    cfg, run, _, params = setup
+    devs = jax.devices()
+    if len(devs) < 2:
+        pytest.skip(f"needs 2 devices, have {len(devs)}")
+    params0 = jax.device_put(params, devs[0])
+    engines, outs = [], []
+    rng = np.random.default_rng(74)
+    prompt = _prompts(cfg, 1, rng, lo=6, hi=7)[0]
+    for i in (0, 1):
+        mesh = compat.make_mesh((1, 1), ("data", "model"), devices=[devs[i]])
+        with mesh:
+            eng = Engine(cfg, run, mesh, cache="paged", slots=2, max_len=16,
+                         num_blocks=8, block_size=4, chunk=4,
+                         placement="auto", engine_id=f"dev{i}")
+            eng.inject_params(params0)
+            eng.submit(Request(0, prompt, max_new_tokens=3))
+            outs.append(list(eng.run_until_drained()[0].out_tokens))
+        engines.append(eng)
+    for i, eng in enumerate(engines):
+        for leaf in jax.tree.leaves(eng.params) + jax.tree.leaves(eng.cache):
+            assert leaf.devices() == {devs[i]}, (i, leaf.devices())
+    assert outs[0] == outs[1]
+    # the lease holds the engine's own (placed) arrays: auto stayed warm
+    assert engines[1].metrics()["fabric"]["placements"][
+        "engine.paged_step"] == "injected"
+
+
 def test_export_import_roundtrip_and_source_handle_detach(setup):
     """Engine-level handoff: a mid-flight request exports into a ticket,
     the source forgets it (its stream handle raises instead of hanging),

@@ -10,7 +10,6 @@ import pytest
 
 from tests.helpers import run_multidev
 
-from repro import compat
 from repro.core.message import FrameSpec, pack_frame
 from repro.kernels.mailbox import am_indirect_put, am_server_sum
 from repro.kernels.mailbox.ref import indirect_put_ref, server_sum_ref
@@ -134,11 +133,6 @@ print("MAILBOX_MULTIDEV_OK")
 """
 
 
-@pytest.mark.skipif(
-    not compat.has_pallas_tpu_interpret(),
-    reason="remote-DMA interpretation needs the TPU-semantics Pallas "
-           "interpreter (pltpu.InterpretParams, jax >= 0.6); the shard_map "
-           "reference transport covers the semantics on older jax")
 def test_mailbox_remote_dma_multidev():
     out = run_multidev(_MULTIDEV, n_devices=4)
     assert "MAILBOX_MULTIDEV_OK" in out

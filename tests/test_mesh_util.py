@@ -7,14 +7,14 @@ pytest.importorskip(
     "hypothesis",
     reason="property tests need hypothesis (see requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ShardingConfig
 from repro.runtime import mesh_util
 
-MESH = compat.abstract_mesh((16, 16), ("data", "model"))
-MESH3 = compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 LOGICAL = st.sampled_from([None, "embed", "vocab", "ff", "moe_ff", "expert",
                            "heads", "kv_heads", "layer", "head_dim"])

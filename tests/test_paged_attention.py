@@ -2,9 +2,8 @@
 
 Three layers of evidence:
 
-  1. differential — the Pallas kernel (generic interpreter on CPU, or the
-     TPU-semantics interpreter where the jax version has one) matches the
-     gather-then-dense oracle within fp tolerance across deterministic
+  1. differential — the Pallas kernel (TPU-semantics interpreter on CPU)
+     matches the gather-then-dense oracle within fp tolerance across deterministic
      sweeps and hypothesis-random block tables (holes, pool-block reuse,
      n_valid in {0, 1, C}, sliding window on/off, block_size in {8, 16});
   2. acceptance — the compiled paged serve step carries no
@@ -17,6 +16,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 
 from repro import compat
@@ -150,11 +150,6 @@ def test_kernel_matches_ref_property():
     run()
 
 
-@pytest.mark.skipif(
-    not compat.has_pallas_tpu_interpret(),
-    reason="TPU-semantics Pallas interpreter (pltpu.InterpretParams, "
-           "jax >= 0.6) not available on this jax; the generic-interpreter "
-           "sweeps above cover kernel semantics")
 def test_kernel_under_tpu_semantics_interpreter():
     """The CI paged-kernel job's target: the same differential check, run
     under the TPU-semantics interpreter (exercises SMEM scalar prefetch and
@@ -163,7 +158,7 @@ def test_kernel_under_tpu_semantics_interpreter():
     rng = np.random.default_rng(11)
     args = _case(rng, bs=8, B=2, C=4, K=2, G=2, D=16, M=3, window=None)
     y = paged_attention_pallas(*args, block_size=8, window=None,
-                               interpret=compat.pallas_tpu_interpret_mode())
+                               interpret=pltpu.InterpretParams())
     yr = paged_attention_ref(*args, block_size=8, window=None)
     _assert_valid_close(y, yr, args[5], **TOL)
 
@@ -245,16 +240,14 @@ def test_modeled_bytes_reduction_at_quarter_occupancy():
 # ---------------------------------------------------------------------------
 
 def test_resolve_kernel_policy():
-    """auto follows platform kernel semantics and is device-count
-    independent — the sharded lowering serves every mesh size, so
+    """auto resolves to the Pallas kernel (compiled on TPU, interpreted
+    elsewhere) and is device-count independent — the sharded lowering serves every mesh size, so
     n_devices never demotes pallas to ref (ISSUE 7)."""
-    expect = "pallas" if (jax.default_backend() == "tpu"
-                          or compat.has_pallas_tpu_interpret()) else "ref"
     for n in (1, 4, 64):
-        assert resolve_kernel("auto", n_devices=n) == expect
+        assert resolve_kernel("auto", n_devices=n) == "pallas"
         assert resolve_kernel("pallas", n_devices=n) == "pallas"
         assert resolve_kernel("ref", n_devices=n) == "ref"
-    assert resolve_kernel("auto") == expect       # n_devices defaults to 1
+    assert resolve_kernel("auto") == "pallas"     # n_devices defaults to 1
     with pytest.raises(ValueError, match="kernel must be one of"):
         resolve_kernel("nope")
 

@@ -1,10 +1,10 @@
-"""Transport seam + JAX-compat contract tests.
+"""Transport seam + mesh contract tests.
 
-Covers: the compat shims (shard_map / make_mesh / abstract_mesh) on the
+Covers: ``compat.make_mesh`` (Auto axes) and ``AbstractMesh`` on the
 installed JAX, the ``sharded_call`` telemetry, the pure auto-mode decision
 (including the per-dp-shard token-count regression), the injected-mode
 weight-gather cache, and the grep-level rule that no module outside
-``repro.compat`` touches raw ``jax.shard_map``.
+``core.transport`` touches raw ``jax.shard_map``.
 """
 import os
 import re
@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh, AxisType, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import compat
@@ -26,44 +27,52 @@ SRC_ROOT = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
 # ---------------------------------------------------------------------------
-# compat shims
+# mesh construction + the shard_map seam
 # ---------------------------------------------------------------------------
 
 def test_compat_shard_map_runs_on_installed_jax():
-    """The shim must build and execute a shard_map on whatever JAX is
-    installed — this is the import-chain bug that took down 7 test modules
-    under jax 0.4.x."""
+    """``sharded_call`` (the repo's one ``jax.shard_map``) builds and runs
+    a collective on a ``compat.make_mesh`` mesh."""
     mesh = compat.make_mesh((1,), ("x",))
 
     def body(v):
         return v + jax.lax.psum(v, "x")
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=P("x"),
-                          out_specs=P("x"), check_vma=False)
+    fn = sharded_call(body, mesh, in_specs=P("x"), out_specs=P("x"),
+                      label="test.compat")
     out = fn(jnp.arange(4.0))
     np.testing.assert_allclose(np.asarray(out), np.arange(4.0) * 2)
 
 
 def test_compat_make_mesh_accepts_and_drops_axis_types():
-    # axis_types must be accepted on every supported version (dropped on
-    # 0.4.x, forwarded on 0.6+); None always works
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                            axis_types=compat.auto_axis_types(2))
+    """``compat.make_mesh`` takes no ``axis_types``: it always builds Auto
+    axes, whatever ``jax.make_mesh`` defaults to. Under an Explicit mesh a
+    ``with_sharding_constraint`` and a plain gather inside ``with mesh:``
+    raise ``ShardingTypeError`` — the failure that took down the serving
+    path."""
+    mesh = compat.make_mesh((1, 1), ("data", "model"))
     assert mesh.axis_names == ("data", "model")
-    mesh2 = compat.make_mesh((1, 1), ("data", "model"))
-    assert dict(mesh2.shape) == {"data": 1, "model": 1}
+    assert dict(mesh.shape) == {"data": 1, "model": 1}
+    assert all(t == AxisType.Auto for t in mesh.axis_types)
+    sh = NamedSharding(mesh, P("data", None))
+    table = jnp.arange(12.0).reshape(6, 2)
+    with mesh:
+        out = jax.jit(lambda t, i: jax.lax.with_sharding_constraint(
+            t[i], sh))(table, jnp.array([[1], [4]]))
+    np.testing.assert_allclose(np.asarray(out)[:, 0], [[2.0, 3.0], [8.0, 9.0]])
 
 
 def test_compat_abstract_mesh_two_arg_form():
-    m = compat.abstract_mesh((16, 16), ("data", "model"))
+    """Device-free meshes (the mesh_util spec tests) default to Auto axes."""
+    m = AbstractMesh((16, 16), ("data", "model"))
     assert m.axis_names == ("data", "model")
     assert dict(m.shape) == {"data": 16, "model": 16}
+    assert all(t == AxisType.Auto for t in m.axis_types)
 
 
 def test_no_raw_shard_map_outside_compat():
-    """Acceptance contract: every shard_map in src/ goes through compat (via
-    core.transport.sharded_call); raw imports would silently re-introduce
-    the version break."""
+    """Every shard_map in src/ goes through ``core.transport.sharded_call``
+    — the one seam that records transport telemetry."""
     pat = re.compile(r"jax\.shard_map|from jax import shard_map")
     offenders = []
     for dirpath, _, files in os.walk(SRC_ROOT):
@@ -71,7 +80,7 @@ def test_no_raw_shard_map_outside_compat():
             if not name.endswith(".py"):
                 continue
             path = os.path.join(dirpath, name)
-            if path.endswith(os.path.join("repro", "compat.py")):
+            if path.endswith(os.path.join("repro", "core", "transport.py")):
                 continue
             with open(path) as fh:
                 for lineno, line in enumerate(fh, 1):

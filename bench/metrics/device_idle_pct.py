@@ -1,0 +1,8 @@
+"""device_idle_pct: 100 times 1 minus the union of the device's operation
+intervals over the traced window."""
+
+
+def read(run):
+    if run.trace is None or run.trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])

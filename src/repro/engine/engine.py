@@ -45,10 +45,10 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig, RunConfig
-from repro.core import transport as transport_lib
 from repro.core.costmodel import TransportEstimate
 from repro.engine.scheduler import (SchedulerPolicy, SchedulerState,
                                     _PolicyBase, resolve_policy)
@@ -76,6 +76,11 @@ class Request:
     ``Engine.submit`` with the engine's tick counter at submission and is
     surfaced — together with per-request TTFT — in
     ``metrics()["requests"]``.
+
+    The ``*_at`` lifecycle stamps are ``time.perf_counter()`` seconds, taken
+    by the engine: at submission, at first admission (a preempted request
+    keeps it), at the first generated token and at completion
+    (docs/engine.md#tracing).
     """
 
     rid: int
@@ -85,6 +90,10 @@ class Request:
     out_tokens: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     arrival_tick: int = -1              # stamped at submit
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
+    done_at: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -123,9 +132,8 @@ class _Entry:
     blocks: List[int] = dataclasses.field(default_factory=list)
     admit_seq: int = -1                 # first-admission stamp (victim order)
     arrival_seq: int = -1               # submit-order stamp (policy ties)
-    submit_time: float = 0.0
-    first_token_time: Optional[float] = None
     first_token_tick: Optional[int] = None
+    last_emit_tick: int = -1            # tick of the newest emitted token
     preemptions: int = 0
     # prompt as python ints, converted once at submit (seq() runs every tick)
     prompt_tokens: List[int] = dataclasses.field(default_factory=list)
@@ -137,6 +145,10 @@ class _Entry:
     def seq(self) -> List[int]:
         """prompt ++ generated — what must be resident before decoding."""
         return self.prompt_tokens + self.req.out_tokens
+
+
+def _since(t: Optional[float], t0: Optional[float]) -> Optional[float]:
+    return None if t is None or t0 is None else t - t0
 
 
 class Engine:
@@ -215,6 +227,11 @@ class Engine:
         self.admission_log: List[int] = []     # rids in first-admission order
         self.peak_active = 0
         self.preempt_count = 0
+        # what the chunked backends' steps were fed (docs/engine.md#tracing)
+        self.tokens_fed = 0
+        self.decode_rows = 0
+        self.prefill_tokens = 0
+        self.step_token_slots = 0
         self.migrations_in = 0
         self.migrations_out = 0
         self._placements: Dict[str, str] = {}
@@ -326,14 +343,6 @@ class Engine:
     def fabric(self):
         """The step bundle's Fabric — the invocation + telemetry surface."""
         return self.bundle.meta.get("fabric")
-
-    @property
-    def transport_decisions(self):
-        """Auto-mode TransportEstimates recorded while tracing the step
-        (delegates to the bundle fabric's decision log)."""
-        if self.fabric is not None:
-            return [est for _, est in self.fabric.decisions]
-        return list(self.bundle.meta.get("transport_log", ()))
 
     def _register_fabric_steps(self) -> None:
         """Register the serve steps as collectives on the bundle fabric so
@@ -631,18 +640,19 @@ class Engine:
         # request's sequence always fits the backend's capacity model
         # (for paged: max_blocks_per_seq blocks, so the block table row
         # cannot overflow and a lone request never starves)
-        msg = self.state.validate(len(req.prompt), req.max_new_tokens,
-                                  self.max_len)
-        if msg:
-            raise ValueError(f"request {req.rid}: {msg}")
-        req.arrival_tick = self.ticks
-        entry = _Entry(req=req, submit_time=time.perf_counter(),
-                       arrival_seq=self._submit_counter,
-                       prompt_tokens=[int(t) for t in req.prompt])
-        self._submit_counter += 1
-        entry.handle = RequestHandle(self, req)
-        self.queue.append(entry)
-        return entry.handle
+        with TraceAnnotation("engine.submit", rid=req.rid):
+            msg = self.state.validate(len(req.prompt), req.max_new_tokens,
+                                      self.max_len)
+            if msg:
+                raise ValueError(f"request {req.rid}: {msg}")
+            req.arrival_tick = self.ticks
+            req.submitted_at = time.perf_counter()
+            entry = _Entry(req=req, arrival_seq=self._submit_counter,
+                           prompt_tokens=[int(t) for t in req.prompt])
+            self._submit_counter += 1
+            entry.handle = RequestHandle(self, req)
+            self.queue.append(entry)
+            return entry.handle
 
     def submit_graph(self, spec, inputs, *, loop_until=None,
                      max_rounds: int = 256, resolve=None,
@@ -690,6 +700,7 @@ class Engine:
             entry.admit_seq = self._admit_counter
             self._admit_counter += 1
             self.admission_log.append(entry.req.rid)
+            entry.req.admitted_at = time.perf_counter()
 
     def _emit(self, entry: _Entry, tok: int) -> None:
         """Append one generated token + TTFT stamps; streaming delivery is
@@ -697,8 +708,9 @@ class Engine:
         client callback can never abort the engine's own bookkeeping
         mid-loop (which would silently drop co-scheduled tokens)."""
         entry.req.out_tokens.append(tok)
+        entry.last_emit_tick = self.ticks
         if len(entry.req.out_tokens) == 1:
-            entry.first_token_time = time.perf_counter()
+            entry.req.first_token_at = time.perf_counter()
             entry.first_token_tick = self.ticks
         if entry.handle is not None:
             self._pending_pump.append(entry)
@@ -715,6 +727,7 @@ class Engine:
 
     def _complete(self, slot: int, entry: _Entry) -> None:
         entry.req.done = True
+        entry.req.done_at = time.perf_counter()
         self.state.release(entry)
         self.completed.append(entry.req)
         self._finished.append(entry)
@@ -734,12 +747,13 @@ class Engine:
         active graph run one round. Returns rows advanced plus node
         invocations fired."""
         self._check_alive("tick")
-        if self.cache_kind == "slots":
-            advanced = self._tick_slots()
-        else:
-            advanced = self._tick_chunked()
-        if self._graphs:
-            advanced += self._tick_graphs()
+        with TraceAnnotation("engine.tick", tick=self.ticks):
+            if self.cache_kind == "slots":
+                advanced = self._tick_slots()
+            else:
+                advanced = self._tick_chunked()
+            if self._graphs:
+                advanced += self._tick_graphs()
         return advanced
 
     # -- slots (fixed-slot contiguous cache) backend ----------------------
@@ -916,15 +930,16 @@ class Engine:
         raises (no preemption path). Reordering policies re-decide at the
         next admission anyway, so the stamp-ordered insert is
         policy-neutral."""
-        slot = self.slot_entry.index(victim)
-        self.cache = self.state.evict(victim, self.cache, slot)
-        victim.preemptions += 1
-        self.preempt_count += 1
-        self.slot_entry[slot] = None
-        at = next((i for i, e in enumerate(self.queue)
-                   if e.admit_seq < 0 or e.admit_seq > victim.admit_seq),
-                  len(self.queue))
-        self.queue.insert(at, victim)
+        with TraceAnnotation("engine.preempt", rid=victim.req.rid):
+            slot = self.slot_entry.index(victim)
+            self.cache = self.state.evict(victim, self.cache, slot)
+            victim.preemptions += 1
+            self.preempt_count += 1
+            self.slot_entry[slot] = None
+            at = next((i for i, e in enumerate(self.queue)
+                       if e.admit_seq < 0 or e.admit_seq > victim.admit_seq),
+                      len(self.queue))
+            self.queue.insert(at, victim)
 
     def _ensure_capacity(self, entry: _Entry, upto_tokens: int) -> None:
         """Grow the entry's state to cover ``upto_tokens``, preempting the
@@ -941,22 +956,25 @@ class Engine:
             self._preempt(victim)
 
     def _tick_chunked(self) -> int:
-        self._admit_chunked()
+        with TraceAnnotation("engine.admit"):
+            self._admit_chunked()
         paged = self.cache_kind == "paged"
 
         # phase A: chunk sizing + capacity growth (may preempt victims,
         # including entries already scheduled earlier in this loop).
         # seq is materialized once per entry per tick — it is O(seq_len).
         sched: List[Tuple[int, _Entry, int, List[int]]] = []
-        for slot in range(self.slots):
-            entry = self.slot_entry[slot]
-            if entry is None:
-                continue
-            seq = entry.seq()
-            n = min(self.chunk, len(seq) - entry.pos)
-            self._ensure_capacity(entry, entry.pos + n)
-            sched.append((slot, entry, n, seq))
-        sched = [item for item in sched if self.slot_entry[item[0]] is item[1]]
+        with TraceAnnotation("engine.schedule"):
+            for slot in range(self.slots):
+                entry = self.slot_entry[slot]
+                if entry is None:
+                    continue
+                seq = entry.seq()
+                n = min(self.chunk, len(seq) - entry.pos)
+                self._ensure_capacity(entry, entry.pos + n)
+                sched.append((slot, entry, n, seq))
+            sched = [item for item in sched
+                     if self.slot_entry[item[0]] is item[1]]
         # the tick counts even when nothing is schedulable, so
         # run_until_drained's max_ticks stays a hard bound (a queue head
         # that can never admit must not spin forever)
@@ -975,39 +993,50 @@ class Engine:
             self._live_frac_ticks += 1
 
         # phase B: build the fixed-shape step inputs
-        tokens = np.zeros((self.slots, self.chunk), np.int32)
-        starts = np.zeros((self.slots,), np.int32)
-        n_valid = np.zeros((self.slots,), np.int32)
-        if paged:
-            tables = np.full((self.slots, self.max_blocks_per_seq), -1,
-                             np.int32)
-        for slot, entry, n, seq in sched:
-            tokens[slot, :n] = seq[entry.pos:entry.pos + n]
+        with TraceAnnotation("engine.inputs"):
+            tokens = np.zeros((self.slots, self.chunk), np.int32)
+            starts = np.zeros((self.slots,), np.int32)
+            n_valid = np.zeros((self.slots,), np.int32)
             if paged:
-                tables[slot, :len(entry.blocks)] = entry.blocks
-            starts[slot] = entry.pos
-            n_valid[slot] = n
+                tables = np.full((self.slots, self.max_blocks_per_seq), -1,
+                                 np.int32)
+            for slot, entry, n, seq in sched:
+                tokens[slot, :n] = seq[entry.pos:entry.pos + n]
+                if paged:
+                    tables[slot, :len(entry.blocks)] = entry.blocks
+                starts[slot] = entry.pos
+                n_valid[slot] = n
+                # a decode row feeds just the token its previous step made
+                if n == 1 and entry.last_emit_tick == self.ticks - 1:
+                    self.decode_rows += 1
+                else:
+                    self.prefill_tokens += n
+                self.tokens_fed += n
+            self.step_token_slots += self.slots * self.chunk
 
-        args = [self.cache, jnp.asarray(tokens)]
-        if paged:
-            args.append(jnp.asarray(tables))
-        args.extend([jnp.asarray(starts), jnp.asarray(n_valid)])
-        next_tok, self.cache = self._step_call(*args)
-        next_np = np.asarray(next_tok)
+            args = [self.cache, jnp.asarray(tokens)]
+            if paged:
+                args.append(jnp.asarray(tables))
+            args.extend([jnp.asarray(starts), jnp.asarray(n_valid)])
+        with TraceAnnotation("engine.dispatch"):
+            next_tok, self.cache = self._step_call(*args)
+        with TraceAnnotation("engine.fetch"):
+            next_np = np.asarray(next_tok)
 
-        for slot, entry, n, seq in sched:
-            known = len(seq)
-            entry.pos += n
-            self.state.append(entry, n)
-            if entry.pos < known:
-                continue                 # mid-prefill: output discarded
-            tok = int(next_np[slot])
-            self._emit(entry, tok)
-            if (len(entry.req.out_tokens) >= entry.req.max_new_tokens
-                    or (self.eos_id is not None and tok == self.eos_id)):
-                self._complete(slot, entry)
-
-        self._flush_streams()
+        with TraceAnnotation("engine.emit"):
+            for slot, entry, n, seq in sched:
+                known = len(seq)
+                entry.pos += n
+                self.state.append(entry, n)
+                if entry.pos < known:
+                    continue             # mid-prefill: output discarded
+                tok = int(next_np[slot])
+                self._emit(entry, tok)
+                if (len(entry.req.out_tokens) >= entry.req.max_new_tokens
+                        or (self.eos_id is not None
+                            and tok == self.eos_id)):
+                    self._complete(slot, entry)
+            self._flush_streams()
         return len(sched)
 
     def preempt(self, rid: int) -> None:
@@ -1157,8 +1186,8 @@ class Engine:
                       priority=ticket.priority,
                       out_tokens=list(ticket.out_tokens))
         req.arrival_tick = self.ticks
-        entry = _Entry(req=req, submit_time=time.perf_counter(),
-                       arrival_seq=self._submit_counter,
+        req.submitted_at = time.perf_counter()
+        entry = _Entry(req=req, arrival_seq=self._submit_counter,
                        prompt_tokens=list(ticket.prompt))
         self._submit_counter += 1
         if ticket.state is not None:
@@ -1174,8 +1203,9 @@ class Engine:
     # ------------------------------------------------------------------
 
     def _request_records(self) -> List[Dict[str, Any]]:
-        """Per-request metrics (submit order): priority/arrival/TTFT —
-        previously reconstructible only from server internals."""
+        """Per-request metrics (submit order): priority/arrival/queue
+        wait/TTFT — previously reconstructible only from server
+        internals."""
         recs = []
         for e in sorted(self._entries_everywhere(),
                         key=lambda e: e.arrival_seq):
@@ -1185,9 +1215,9 @@ class Engine:
                 "priority": r.priority,
                 "arrival_tick": r.arrival_tick,
                 "admitted": e.admit_seq >= 0,
+                "queue_wait_s": _since(r.admitted_at, r.submitted_at),
                 "first_token_tick": e.first_token_tick,
-                "ttft_s": (e.first_token_time - e.submit_time
-                           if e.first_token_time is not None else None),
+                "ttft_s": _since(r.first_token_at, r.submitted_at),
                 "tokens": len(r.out_tokens),
                 "preemptions": e.preemptions,
                 "done": r.done,
@@ -1196,20 +1226,15 @@ class Engine:
 
     def _transport_metrics(self) -> Dict[str, Any]:
         """Transport telemetry block of ``metrics()`` — delegates to the
-        bundle fabric (the ``fabric`` key carries its full ``metrics()``
-        dict plus the resolved placement of every engine-registered step);
-        the two legacy keys are kept for pre-Fabric consumers."""
-        out: Dict[str, Any] = {
-            "transport_decisions": [est.describe()
-                                    for est in self.transport_decisions],
-            "transport_telemetry": transport_lib.get_telemetry().summary(),
-        }
-        if self.fabric is not None:
-            fm = self.fabric.metrics()
-            fm["placements"] = dict(self._placements)
-            fm["lease_fallbacks"] = self.lease_fallbacks
-            out["fabric"] = fm
-        return out
+        bundle fabric: the ``fabric`` key carries its full ``metrics()``
+        dict (decision log and transport telemetry included) plus the
+        resolved placement of every engine-registered step."""
+        if self.fabric is None:
+            return {}
+        fm = self.fabric.metrics()
+        fm["placements"] = dict(self._placements)
+        fm["lease_fallbacks"] = self.lease_fallbacks
+        return {"fabric": fm}
 
     def metrics(self) -> Dict[str, Any]:
         """Unified engine telemetry snapshot (JSON-friendly).
@@ -1220,9 +1245,9 @@ class Engine:
         (same names the legacy paged server reported). docs/engine.md
         documents every key.
         """
-        done = [e for e in self._entries_everywhere() if e.req.done]
-        ttfts = sorted(e.first_token_time - e.submit_time
-                       for e in done if e.first_token_time is not None)
+        done = [e.req for e in self._entries_everywhere() if e.req.done]
+        ttfts = sorted(t for t in (_since(r.first_token_at, r.submitted_at)
+                                   for r in done) if t is not None)
         out: Dict[str, Any] = {
             "engine": {
                 # engine_id first: the merge key multi-replica metric
@@ -1255,6 +1280,11 @@ class Engine:
                 "runs": [run.metrics()
                          for run in self._graphs + self._graphs_done],
             }
+        if self.cache_kind != "slots":
+            out.update({"tokens_fed": self.tokens_fed,
+                        "decode_rows": self.decode_rows,
+                        "prefill_tokens": self.prefill_tokens,
+                        "step_token_slots": self.step_token_slots})
         if self.cache_kind == "paged":
             out.update({
                 "paged_kernel": self.paged_kernel,

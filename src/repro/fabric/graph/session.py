@@ -59,9 +59,9 @@ class DecodeSession:
         self.placement = placement or engine.placement
         self.sid = next(_sids)
         req = Request(rid=self.sid, prompt=np.asarray(prompt, np.int32),
-                      max_new_tokens=engine.max_len - len(prompt))
-        self.entry = _Entry(req=req, submit_time=time.perf_counter(),
-                            prompt_tokens=list(prompt))
+                      max_new_tokens=engine.max_len - len(prompt),
+                      submitted_at=time.perf_counter())
+        self.entry = _Entry(req=req, prompt_tokens=list(prompt))
         self.steps = 0                  # decode/prefill step invocations
         self.verify_steps = 0           # multi-token verify invocations
         self.released = False

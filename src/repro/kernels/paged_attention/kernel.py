@@ -185,6 +185,7 @@ def paged_attention_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(tables, starts, seq_end, qg, k_pool, v_pool)
     return (out.reshape(B, K, G, C, D).transpose(0, 3, 1, 2, 4)
             .reshape(B, C, H, D))

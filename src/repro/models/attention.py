@@ -333,7 +333,8 @@ def gqa_paged_attention(
         q = apply_rope(q, positions, a.rope_theta, a.rotary_pct)
         k = apply_rope(k, positions, a.rope_theta, a.rotary_pct)
 
-    new_cache = cache.write(k, v, layout)
+    with jax.named_scope("kv_pool"):
+        new_cache = cache.write(k, v, layout)
     if callable(kernel):
         fn = kernel
     else:

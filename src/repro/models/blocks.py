@@ -266,22 +266,25 @@ def _apply_block_paged(bt: str, params, x: jax.Array, cfg: ModelConfig,
         raise ValueError(f"block type {bt!r} has no paged path")
     a = cfg.attention
     window = a.sliding_window if bt.endswith("_local") else None
-    h = rms_norm(x, params["ln1"], cfg.norm_eps)
-    pkv = PagedKVCache(cache["k"], cache["v"], paged.block_size)
-    y_attn, npkv = attn.gqa_paged_attention(params["attn"], h, a,
-                                            cache=pkv, layout=paged,
-                                            window=window,
-                                            kernel=paged_kernel)
-    x = x + y_attn
-    h2 = rms_norm(x, params["ln2"], cfg.norm_eps)
-    if bt.endswith("_moe"):
-        # mask the padding columns out of routing so they cannot steal
-        # expert capacity from real tokens (same drop-slot rule on the
-        # oracle and every jam transport — docs/fabric.md)
-        y_ffn, aux = moe_mod.moe_ffn(params["moe"], h2, cfg.moe, cfg.act,
-                                     transport=moe_transport,
-                                     token_mask=paged.token_valid(x.shape[1]))
-    else:
-        y_ffn = mlp_mod.mlp(params["mlp"], h2, cfg.act, cfg.mlp_gated)
-        aux = jnp.zeros((), jnp.float32)
-    return x + y_ffn, {"k": npkv.k_pool, "v": npkv.v_pool}, aux
+    with jax.named_scope("attention"):
+        h = rms_norm(x, params["ln1"], cfg.norm_eps)
+        pkv = PagedKVCache(cache["k"], cache["v"], paged.block_size)
+        y_attn, npkv = attn.gqa_paged_attention(params["attn"], h, a,
+                                                cache=pkv, layout=paged,
+                                                window=window,
+                                                kernel=paged_kernel)
+        x = x + y_attn
+    with jax.named_scope("mlp"):
+        h2 = rms_norm(x, params["ln2"], cfg.norm_eps)
+        if bt.endswith("_moe"):
+            # mask the padding columns out of routing so they cannot steal
+            # expert capacity from real tokens (same drop-slot rule on the
+            # oracle and every jam transport — docs/fabric.md)
+            y_ffn, aux = moe_mod.moe_ffn(
+                params["moe"], h2, cfg.moe, cfg.act, transport=moe_transport,
+                token_mask=paged.token_valid(x.shape[1]))
+        else:
+            y_ffn = mlp_mod.mlp(params["mlp"], h2, cfg.act, cfg.mlp_gated)
+            aux = jnp.zeros((), jnp.float32)
+        x = x + y_ffn
+    return x, {"k": npkv.k_pool, "v": npkv.v_pool}, aux

@@ -216,8 +216,9 @@ def forward(
         x = jnp.einsum("btf,fd->btd", frontend_feats.astype(compute_dtype),
                        params["frontend_proj"].astype(compute_dtype))
     else:
-        x = params["embed"].astype(compute_dtype)[tokens]
-        x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
+        with jax.named_scope("embed"):
+            x = params["embed"].astype(compute_dtype)[tokens]
+            x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
         if cfg.frontend.kind == "vision_patches" and frontend_feats is not None:
             # splice precomputed image-patch embeddings over the first P slots
             p = frontend_feats.shape[1]
@@ -293,11 +294,13 @@ def forward(
             new_pat_cache = pat_cache
             for r in range(repeats):
                 lp = jax.tree.map(lambda t: t[r], pat_params)
-                lc = jax.tree.map(lambda t: t[r], new_pat_cache)
+                with jax.named_scope("kv_pool"):
+                    lc = jax.tree.map(lambda t: t[r], new_pat_cache)
                 (x, aux_total), out_lc = body((x, aux_total), (lp, lc))
-                new_pat_cache = jax.tree.map(
-                    lambda full, one: full.at[r].set(one),
-                    new_pat_cache, out_lc)
+                with jax.named_scope("kv_pool"):
+                    new_pat_cache = jax.tree.map(
+                        lambda full, one: full.at[r].set(one),
+                        new_pat_cache, out_lc)
         elif repeats > 1:
             scan_body = body
             if cfg.remat == "full":
@@ -309,12 +312,16 @@ def forward(
                                                  (pat_params, pat_cache))
         new_groups.append(new_pat_cache)
 
-    x = rms_norm(x, params["final_norm"].astype(compute_dtype), cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(compute_dtype))
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x, params["head"].astype(compute_dtype))
-    logits = softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"].astype(compute_dtype),
+                     cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["embed"].astype(compute_dtype))
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x,
+                                params["head"].astype(compute_dtype))
+        logits = softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
 
     new_cache = None
     if cache is not None:

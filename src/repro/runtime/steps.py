@@ -442,10 +442,11 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
             # verify wiring: greedy choice at every fed position
             next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return next_tok, new_cache                        # (slots, chunk)
-        last = jnp.maximum(n_valid - 1, 0)
-        last_logits = jnp.take_along_axis(
-            logits, last[:, None, None], axis=1)[:, 0]        # (slots, V)
-        next_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            last = jnp.maximum(n_valid - 1, 0)
+            last_logits = jnp.take_along_axis(
+                logits, last[:, None, None], axis=1)[:, 0]    # (slots, V)
+            next_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         return next_tok, new_cache
 
     cache_shapes = jax.eval_shape(
@@ -518,10 +519,11 @@ def make_recurrent_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
         logits, new_cache, _ = model_lib.forward(
             cfg, params, tokens, cache=cache, recurrent=layout,
             moe_transport=transport, constrain=constrain)
-        last = jnp.maximum(n_valid - 1, 0)
-        last_logits = jnp.take_along_axis(
-            logits, last[:, None, None], axis=1)[:, 0]        # (slots, V)
-        next_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            last = jnp.maximum(n_valid - 1, 0)
+            last_logits = jnp.take_along_axis(
+                logits, last[:, None, None], axis=1)[:, 0]    # (slots, V)
+            next_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
         return next_tok, new_cache
 
     cache_shapes = jax.eval_shape(

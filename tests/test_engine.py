@@ -404,7 +404,7 @@ def test_unified_metrics_schema_both_backends(setup):
     cfg, run, mesh, params = setup
     core_keys = ("engine", "ticks", "active_slots", "peak_active_slots",
                  "queued", "completed", "preemptions", "ttft_s", "requests",
-                 "transport_decisions", "transport_telemetry", "fabric")
+                 "fabric")
     paged = _mk_engine(setup)
     with mesh:
         slots = Engine(cfg, run, mesh, cache="slots", slots=2, max_len=32)
@@ -414,6 +414,8 @@ def test_unified_metrics_schema_both_backends(setup):
         m = eng.metrics()
         for key in core_keys:
             assert key in m, (cache, key)
+        for key in ("decisions", "transport_telemetry"):
+            assert key in m["fabric"], (cache, key)
         assert m["engine"]["cache"] == cache
         assert m["engine"]["scheduler"] == "fifo"
         # fabric-routed placement: the registered steps resolve "local"
@@ -766,7 +768,7 @@ def test_placement_modes_identical_tokens_and_lease_telemetry(setup):
 
     m = engines["auto"].metrics()
     assert m["fabric"]["placements"]["engine.paged_step"] == "local"
-    decs = m["transport_decisions"]
+    decs = m["fabric"]["decisions"]
     assert len(decs) == engines["auto"].ticks
     assert all(d.endswith("-> local") for d in decs)
 
@@ -786,7 +788,7 @@ def test_inject_params_makes_auto_resolve_injected(setup):
     assert m["fabric"]["placements"]["engine.paged_step"] == "injected"
     lease = m["fabric"]["leases"]["engine.paged_step.params"]
     assert lease["misses"] == 1 and lease["hits"] == eng.ticks
-    assert all(d.endswith("-> injected") for d in m["transport_decisions"])
+    assert all(d.endswith("-> injected") for d in m["fabric"]["decisions"])
 
 
 def test_inject_params_places_tree_on_engine_device(setup):

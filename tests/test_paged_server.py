@@ -217,9 +217,10 @@ def test_metrics_schema(setup):
                 "free_blocks", "used_blocks", "peak_used_blocks",
                 "occupancy", "preemptions", "ttft_s",
                 "paged_kernel", "live_token_fraction",
-                "live_token_fraction_mean",
-                "transport_decisions", "transport_telemetry"):
+                "live_token_fraction_mean"):
         assert key in m, key
+    for key in ("decisions", "transport_telemetry"):
+        assert key in m["fabric"], key
     assert m["paged_kernel"] in ("pallas", "ref")
 
 

@@ -1288,6 +1288,9 @@ class Engine:
         if self.cache_kind == "paged":
             out.update({
                 "paged_kernel": self.paged_kernel,
+                "kv_pool_in_place_layers":
+                    self.bundle.meta["kv_pool_in_place_layers"],
+                "kv_pool_layers": self.bundle.meta["kv_pool_layers"],
                 "live_token_fraction": self._live_frac_last,
                 "live_token_fraction_mean": (
                     self._live_frac_sum / self._live_frac_ticks

@@ -185,6 +185,31 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     return {"groups": groups}
 
 
+def pool_writes_in_place(pool: PyTree) -> bool:
+    """Whether a stacked paged-pool group is written through its flat view.
+
+    Merging the leading (L, N) axes of an (L, N, bs, K, D) leaf is free only
+    where the device keeps the leaf row-major. The TPU does so when (K, D)
+    fill whole (8, 128) tiles; for D=96 or 64 its default layout puts the
+    block axis N innermost (Phi-3's pool: ``bf16[32,1017,16,32,96]
+    {1,4,3,2,0}``), the flat view becomes a transposing copy of the pool, and
+    the per-layer slice path is the cheaper one (PERF.md §5).
+    """
+    return all(t.shape[-1] % 128 == 0 and t.shape[-2] % 8 == 0
+               for t in jax.tree.leaves(pool))
+
+
+def paged_in_place_layers(cfg: ModelConfig, cache: PyTree) -> Tuple[int, int]:
+    """(layers whose pool the paged step writes in place, all layers)."""
+    in_place = total = 0
+    for (pattern, repeats), group in zip(layer_plan(cfg), cache["groups"]):
+        n = repeats * len(pattern)
+        total += n
+        if pool_writes_in_place(group):
+            in_place += n
+    return in_place, total
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -259,7 +284,7 @@ def forward(
             params["groups"][g_idx])
         pat_cache = cache["groups"][g_idx] if cache is not None else None
 
-        def body(carry, per_layer, pattern=pattern):
+        def body(carry, per_layer, pattern=pattern, layout=paged):
             x_c, aux_c = carry
             lp, lc = per_layer
             new_lc = []
@@ -269,7 +294,7 @@ def forward(
                     bt, lp[p_idx],
                     x_c, cfg, cache=c_in, length=length,
                     positions=positions, mrope_positions=mrope_positions,
-                    moe_transport=moe_transport, paged=paged,
+                    moe_transport=moe_transport, paged=layout,
                     paged_kernel=paged_kernel, recurrent=recurrent)
                 x_c = constrain(x_c)
                 new_lc.append(c_out)
@@ -280,7 +305,10 @@ def forward(
         # (~170 GiB temps at 32k x B128). Unrolled, each layer's update is
         # DUS(DS(stacked)) — in place on the donated cache buffer. Paged
         # steps always unroll for the same reason: the pool is the dominant
-        # buffer and must update in place on the donated argument.
+        # buffer and must update in place on the donated argument. Where its
+        # layout allows (``pool_writes_in_place``), they scatter into a flat
+        # view of the stacked pool instead: DUS(DS(stacked)) still copies
+        # each layer's whole pool out and back.
         # Pure-recurrent stacks (xLSTM, SSM-only) always scan: the state is
         # constant-size (double-buffering is cheap) and scan-vs-unroll round
         # differently at 1 bf16 ulp — keeping every path (prefill, S==1
@@ -290,7 +318,27 @@ def forward(
             bt in blocks_mod.RECURRENT_BLOCK_TYPES for bt in pattern)
         unroll = cache is not None and (
             paged is not None or (S == 1 and not pure_recurrent))
-        if repeats > 1 and unroll:
+        if repeats > 1 and unroll and paged is not None and (
+                pool_writes_in_place(pat_cache)):
+            # one flat (L*N, bs, K, D) view of each stacked pool leaf: layer
+            # r addresses its blocks at r*N onward, so the scatter writes
+            # the donated buffer itself — no per-layer slice or write-back
+            n_blocks = jax.tree.leaves(pat_cache)[0].shape[1]
+            with jax.named_scope("kv_pool"):
+                flat = jax.tree.map(lambda t: t.reshape(-1, *t.shape[2:]),
+                                    pat_cache)
+            for r in range(repeats):
+                lp = jax.tree.map(lambda t: t[r], pat_params)
+                with jax.named_scope("kv_pool"):
+                    tables = paged.block_tables
+                    layer_paged = paged._replace(block_tables=jnp.where(
+                        tables >= 0, tables + r * n_blocks, tables))
+                (x, aux_total), flat = body((x, aux_total), (lp, flat),
+                                            layout=layer_paged)
+            with jax.named_scope("kv_pool"):
+                new_pat_cache = jax.tree.map(
+                    lambda f, t: f.reshape(t.shape), flat, pat_cache)
+        elif repeats > 1 and unroll:
             new_pat_cache = pat_cache
             for r in range(repeats):
                 lp = jax.tree.map(lambda t: t[r], pat_params)

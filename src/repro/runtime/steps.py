@@ -404,6 +404,11 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
     replicated at the step boundary and are sliced per dp shard inside the
     shard_map (docs/serving.md#the-paged-attention-kernel).
 
+    ``meta["kv_pool_in_place_layers"]`` of ``meta["kv_pool_layers"]``
+    layers scatter their new k/v straight into the donated pool
+    (``model.pool_writes_in_place``); the rest copy their pool slice out
+    and back.
+
     MoE archs on a >1-shard tensor axis serve through the token-mask-aware
     jam transports: the padding-column mask from ``PagedLayout.token_valid``
     threads into ``core.dispatch``'s shard bodies so padding can never
@@ -451,6 +456,8 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
 
     cache_shapes = jax.eval_shape(
         lambda: model_lib.init_paged_cache(cfg, num_blocks, block_size))
+    in_place_layers, pool_layers = model_lib.paged_in_place_layers(
+        cfg, cache_shapes)
     cache_shard = jax.tree.map(
         lambda s: NamedSharding(mesh, s),
         mesh_util.paged_cache_spec_tree(cache_shapes, rules, mesh),
@@ -476,7 +483,9 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
                   cache=cache_shapes, transport_log=transport_log,
                   fabric=fabric, block_size=block_size,
                   num_blocks=num_blocks, chunk=chunk, slots=slots,
-                  paged_kernel=paged_kernel, emit=emit),
+                  paged_kernel=paged_kernel, emit=emit,
+                  kv_pool_in_place_layers=in_place_layers,
+                  kv_pool_layers=pool_layers),
     )
 
 

@@ -3,22 +3,30 @@
 Interpret mode never checks the Mosaic lowering rules (block-shape tiling,
 VMEM limits), so these tests compile the kernel for a *described* v5e chip
 with the TPU compiler installed alongside JAX — no chip attached. They cover
-decode (C=1) and the chunk width the serving launcher uses.
+decode (C=1) and the chunk width the serving launcher uses. The paged step
+itself compiles at the benchmark's widths (fewer layers, a smaller pool), to
+pin which pools the TPU compiler lets the step write in place.
 
 The topology is described only inside the module-scoped fixture below, never
 at import time: the TPU library may be loaded by one process at a time, and
 every pytest worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
+from repro.configs.base import (SHAPES, AttentionConfig, ModelConfig,
+                                RunConfig, ShardingConfig)
 from repro.configs.registry import get_config
+from repro.kernels.paged_attention import ops
 from repro.kernels.paged_attention.kernel import paged_attention_pallas
 from repro.launch.serve import DEFAULT_CHUNK
+from repro.runtime.steps import make_paged_serve_step
 
 BLOCK_SIZE = 16
 SLOTS = 8
@@ -59,3 +67,52 @@ def test_paged_kernel_compiles_for_v5e_at_llama32_1b_widths(one_chip, chunk):
         *xs, block_size=BLOCK_SIZE, interpret=False))
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+STEP_LAYERS, STEP_BLOCKS = 2, 256
+
+
+def _pool_copies(hlo: str, pool_shape) -> list:
+    """Slices and dynamic-update-slices whose output is one layer's pool
+    (``[N,bs,K,D]``, ``[1,N,bs,K,D]``) or the layer-stacked pool."""
+    dims = ",".join(map(str, pool_shape))
+    return re.findall(
+        rf"= bf16\[(?:\d+,)?{dims}\]\{{[^}}]*\}} "
+        r"(slice|slice-start|dynamic-slice|dynamic-update-slice)\(", hlo)
+
+
+@pytest.mark.parametrize("name,d_model,d_ff,vocab,H,K,D,in_place", [
+    ("mistral-7b-v0.3", 4096, 14336, 32768, 32, 8, 128, True),
+    ("phi3-mini-4k", 3072, 8192, 32064, 32, 32, 96, False),
+])
+def test_paged_step_writes_lane_aligned_pool_in_place(
+        topo, monkeypatch, name, d_model, d_ff, vocab, H, K, D, in_place):
+    """With D=128 the step scatters into the stacked pool: no layer's pool
+    is sliced out or written back. With D=96 the TPU keeps the block axis
+    innermost, and the step keeps the per-layer slice path."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = ModelConfig(
+        name=name, num_layers=STEP_LAYERS, d_model=d_model, d_ff=d_ff,
+        vocab_size=vocab, remat="none",
+        attention=AttentionConfig(kind="gqa", num_heads=H, num_kv_heads=K,
+                                  head_dim=D))
+    run = RunConfig(model=cfg, shape=SHAPES["decode_32k"],
+                    sharding=ShardingConfig(fsdp_params=False, seq_axis=None))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    with mesh:
+        b = make_paged_serve_step(
+            cfg, run, mesh, slots=SLOTS, chunk=DEFAULT_CHUNK,
+            num_blocks=STEP_BLOCKS, block_size=BLOCK_SIZE,
+            max_blocks_per_seq=MAX_BLOCKS_PER_SEQ, kernel="pallas")
+        hlo = jax.jit(b.fn, in_shardings=b.in_shardings,
+                      out_shardings=b.out_shardings, donate_argnums=(1,)
+                      ).lower(*b.abstract_inputs).compile().as_text()
+    assert hlo.count("tpu_custom_call") >= STEP_LAYERS
+    assert b.meta["kv_pool_layers"] == STEP_LAYERS
+    assert b.meta["kv_pool_in_place_layers"] == (STEP_LAYERS if in_place
+                                                 else 0)
+    copies = _pool_copies(hlo, (STEP_BLOCKS, BLOCK_SIZE, K, D))
+    if in_place:
+        assert copies == []
+    else:
+        assert "slice" in copies and "dynamic-update-slice" in copies

@@ -11,12 +11,27 @@ from __future__ import annotations
 import dataclasses
 import json
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Optional, Tuple
 
 
 # ---------------------------------------------------------------------------
 # Attention
 # ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (``rope_scaling`` of type ``"yarn"``): rotary
+    frequencies ramped between the original and the ``factor``-interpolated
+    ones, and the attention scale multiplied by ``mscale(mscale_all_dim)``
+    squared (DeepSeek-V2's reading of arXiv:2309.00071)."""
+
+    factor: float = 1.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
 
 @dataclass(frozen=True)
 class AttentionConfig:
@@ -43,6 +58,7 @@ class AttentionConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    yarn: Optional[YarnConfig] = None
 
     @property
     def q_dim(self) -> int:
@@ -78,6 +94,20 @@ class MoEConfig:
     transport: str = "local"
     # First k layers use a dense FFN instead of MoE (deepseek-v2: 1).
     first_dense_layers: int = 0
+    # Gates: the top-k softmax probabilities, renormalised over the k picks
+    # or not (deepseek-v2: not), then times ``routed_scaling_factor``.
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # The experts this layer holds, ``[lo, hi)`` of ``num_experts`` (expert
+    # parallelism: one chip's share). Set, the layer routes over all
+    # experts and computes, dropping no token, only its own experts' part;
+    # None keeps every expert and the capacity-bucketed dispatch.
+    experts_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def num_held(self) -> int:
+        lo, hi = self.experts_held or (0, self.num_experts)
+        return hi - lo
 
 
 # ---------------------------------------------------------------------------
@@ -207,10 +237,9 @@ def _layer_params(cfg: ModelConfig, layer_idx: int, active_only: bool) -> int:
     moe = cfg.moe
     use_moe = moe is not None and layer_idx >= (moe.first_dense_layers if moe else 0)
     if use_moe:
-        n_e = (moe.num_shared + moe.top_k) if active_only else (moe.num_shared + moe.num_experts)
         shared = moe.num_shared * _ffn_params(d, moe.shared_ff or moe.expert_ff)
         routed_each = _ffn_params(d, moe.expert_ff)
-        n_routed = moe.top_k if active_only else moe.num_experts
+        n_routed = moe.top_k if active_only else moe.num_held
         p += shared + n_routed * routed_each + d * moe.num_experts  # + router
     elif cfg.d_ff > 0:
         p += _ffn_params(d, cfg.d_ff, cfg.mlp_gated)

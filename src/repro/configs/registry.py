@@ -40,14 +40,15 @@ def default_cache_backend(cfg: ModelConfig) -> str:
 
     Recurrent stacks (xLSTM, pure SSM) carry constant-size state — the
     recurrent backend serves them exactly AND preempts for free. Archs the
-    paged pool cannot hold (MLA latents, hybrid attn+SSM, mrope position
-    streams) fall back to the contiguous slots rows. Plain-GQA archs get
-    the paged pool (docs/serving.md has the full backend table).
+    paged pool cannot hold (hybrid attn+SSM, mrope position streams) fall
+    back to the contiguous slots rows. GQA archs get the paged pool, and so
+    do MLA archs, whose latents it holds one leaf a layer (docs/serving.md
+    has the full backend table).
     """
     if cfg.xlstm is not None or (cfg.ssm is not None and cfg.attention is None):
         return "recurrent"
     a = cfg.attention
-    if cfg.parallel_ssm_attn or (a is not None and (a.kind == "mla" or a.mrope)):
+    if cfg.parallel_ssm_attn or (a is not None and a.mrope):
         return "slots"
     return "paged"
 

@@ -37,14 +37,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.configs.base import MoEConfig
-from repro.models.common import act_fn
-from repro.models.moe import build_dispatch, expert_capacity, expert_ffn, route_topk
-
-
-def _shared_expert(params, xf, act):
-    g = jnp.einsum("nd,df->nf", xf, params["ws_gate"])
-    u = jnp.einsum("nd,df->nf", xf, params["ws_up"])
-    return jnp.einsum("nf,fd->nd", act_fn(act)(g) * u, params["ws_down"])
+from repro.models.moe import (build_dispatch, expert_capacity, expert_ffn,
+                              route_topk, shared_expert_ffn)
 
 
 def _combine(out_rows: jax.Array, slot: jax.Array, keep: jax.Array,
@@ -136,7 +130,7 @@ def _local_body(router, wg, wu, wd, shared, xf, tm=None, *, m: MoEConfig,
 
     y_loc = _combine(rows, slot, keep, gates, xf.dtype)
     if shared is not None:
-        y_loc = y_loc + _shared_expert(shared, xloc, act)
+        y_loc = y_loc + shared_expert_ffn(shared, xloc, act)
 
     y = jax.lax.all_gather(y_loc, tp_axis, axis=0, tiled=True)  # (N, d)
     return y, _aux_pmean(r.aux_loss + r.z_loss, tp_axis, dp_axes)
@@ -163,7 +157,7 @@ def _injected_body(router, wg_full, wu_full, wd_full, shared, xf, tm=None, *,
 
     y_loc = _combine(rows, slot, keep, gates, xf.dtype)
     if shared is not None:
-        y_loc = y_loc + _shared_expert(shared, xloc, act)
+        y_loc = y_loc + shared_expert_ffn(shared, xloc, act)
 
     y = jax.lax.all_gather(y_loc, tp_axis, axis=0, tiled=True)
     return y, _aux_pmean(r.aux_loss + r.z_loss, tp_axis, dp_axes)
@@ -196,7 +190,7 @@ def _tp_body(router, wg, wu, wd, shared, xf, tm=None, *, m: MoEConfig,
     if shared is not None:
         # shared weights + tokens are replicated over tp, so adding the
         # shared-expert output on every rank keeps y replicated
-        y = y + _shared_expert(shared, xf, act)
+        y = y + shared_expert_ffn(shared, xf, act)
     return y, _aux_pmean(r.aux_loss + r.z_loss, tp_axis, dp_axes)
 
 

@@ -62,6 +62,9 @@ from repro.runtime.steps import (make_paged_serve_step,
                                  make_recurrent_serve_step, make_serve_step,
                                  sharding_ctx)
 
+# Engine.metrics() keys of an expert-parallel share's step counters
+MOE_COUNTERS = ("moe_pairs", "moe_pairs_here", "moe_expert_pairs_max")
+
 PyTree = Any
 
 __all__ = ["Request", "BlockPool", "Engine", "MigrationTicket"]
@@ -232,6 +235,9 @@ class Engine:
         self.decode_rows = 0
         self.prefill_tokens = 0
         self.step_token_slots = 0
+        # expert-parallel share's counters, summed over the expert layers
+        # and the ticks' steps (runtime.steps, meta["moe_counters"])
+        self.moe_counts = dict.fromkeys(MOE_COUNTERS, 0)
         self.migrations_in = 0
         self.migrations_out = 0
         self._placements: Dict[str, str] = {}
@@ -403,8 +409,9 @@ class Engine:
         """A graph session's step invocation — same fabric-registered
         step as ``tick``, but at the session's own placement."""
         self._check_alive("session step")
-        return self.fabric.call(self._step_name, args, state=self.params,
-                                placement=placement or self.placement)
+        out = self.fabric.call(self._step_name, args, state=self.params,
+                               placement=placement or self.placement)
+        return out[:2]              # (tokens, cache); counters are the tick's
 
     def ensure_verify_step(self) -> None:
         """Build + register the multi-token verify step lazily (paged
@@ -1019,9 +1026,15 @@ class Engine:
                 args.append(jnp.asarray(tables))
             args.extend([jnp.asarray(starts), jnp.asarray(n_valid)])
         with TraceAnnotation("engine.dispatch"):
-            next_tok, self.cache = self._step_call(*args)
+            out = self._step_call(*args)
+            next_tok, self.cache = out[0], out[1]
         with TraceAnnotation("engine.fetch"):
-            next_np = np.asarray(next_tok)
+            if len(out) > 2:
+                next_np, counts = jax.device_get((next_tok, out[2]))
+                for k, v in zip(MOE_COUNTERS, counts):
+                    self.moe_counts[k] += int(v)
+            else:
+                next_np = np.asarray(next_tok)
 
         with TraceAnnotation("engine.emit"):
             for slot, entry, n, seq in sched:
@@ -1291,6 +1304,8 @@ class Engine:
                 "kv_pool_in_place_layers":
                     self.bundle.meta["kv_pool_in_place_layers"],
                 "kv_pool_layers": self.bundle.meta["kv_pool_layers"],
+                **(self.moe_counts if self.bundle.meta["moe_counters"]
+                   else {}),
                 "live_token_fraction": self._live_frac_last,
                 "live_token_fraction_mean": (
                     self._live_frac_sum / self._live_frac_ticks

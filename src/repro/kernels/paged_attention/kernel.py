@@ -39,6 +39,11 @@ the same block — and ``pl.when`` skips their compute. Work therefore scales
 with resident tokens, not pool capacity: one fixed compiled shape serves
 decode rows (``n_valid == 1``), chunked-prefill rows (``n_valid <= C``),
 and idle rows (``n_valid == 0``, which touch zero blocks).
+
+Latent variant (``paged_latent_attention_pallas``, absorbed MLA): MQA over a
+``(N, bs, lanes)`` pool of latent rows, one shared "head" that is the key
+over all its lanes and the value over its first ``v_lanes``; same grid,
+tables and early exit, one DMA a block for both products.
 """
 from __future__ import annotations
 
@@ -189,3 +194,116 @@ def paged_attention_pallas(
     )(tables, starts, seq_end, qg, k_pool, v_pool)
     return (out.reshape(B, K, G, C, D).transpose(0, 3, 1, 2, 4)
             .reshape(B, C, H, D))
+
+
+# ---------------------------------------------------------------------------
+# latent variant (MLA): one shared latent "head" serves as k and v
+# ---------------------------------------------------------------------------
+
+def _latent_kernel(tables_ref, starts_ref, seq_end_ref, q_ref, c_ref, o_ref,
+                   m_ref, l_ref, acc_ref, *, bs: int, chunk: int, v_lanes: int,
+                   scale: float):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    hc = q_ref.shape[1]                           # H * C rows
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    start = starts_ref[b]
+    seq_end = seq_end_ref[b]
+
+    @pl.when(j < (seq_end + bs - 1) // bs)
+    def _block():
+        # row r = h * C + c serves chunk column c = r % C of head h
+        q_pos = start + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (hc, bs), 0), chunk)
+        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (hc, bs), 1)
+        mask = (q_pos >= k_pos) & (k_pos < seq_end)
+        c = c_ref[0]                              # (bs, lanes)
+        s = jax.lax.dot_general(
+            q_ref[0], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (H*C, bs)
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+        m_ref[...] = m_new
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c[:, :v_lanes], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == nj - 1)
+    def _flush():
+        o_ref[0] = (acc_ref[...]
+                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_latent_attention_pallas(
+    q: jax.Array,                      # (B, C, H, lanes)
+    pool: jax.Array,                   # (N_blocks, block_size, lanes)
+    block_tables: jax.Array,           # (B, M) int32
+    starts: jax.Array,                 # (B,) int32
+    n_valid: jax.Array,                # (B,) int32
+    *,
+    block_size: int,
+    v_lanes: int,
+    scale: float,
+    interpret=False,
+) -> jax.Array:
+    """Paged MQA over a latent pool (absorbed MLA). Returns (B, C, H,
+    v_lanes).
+
+    Every query head scores against one latent row per token (all
+    ``lanes``: the compressed kv, the rotary key and any zero padding, which
+    the query pads with zeros too) and averages the row's first
+    ``v_lanes`` lanes (the compressed kv). One grid step DMAs one pool
+    block ``(1, bs, lanes)`` once and uses it for both products. Grid,
+    block tables and the clamping of dead steps are those of
+    ``paged_attention_pallas``.
+    """
+    B, C, H, lanes = q.shape
+    bs = block_size
+    assert pool.shape[1:] == (bs, lanes), (pool.shape, bs, lanes)
+    M = block_tables.shape[1]
+    qh = q.transpose(0, 2, 1, 3).reshape(B, H * C, lanes)
+    tables = block_tables.astype(jnp.int32)
+    starts = starts.astype(jnp.int32)
+    seq_end = starts + n_valid.astype(jnp.int32)
+
+    def q_map(b, j, tables, starts, seq_end):
+        return (b, 0, 0)
+
+    def c_map(b, j, tables, starts, seq_end):
+        last = jnp.maximum((seq_end[b] + bs - 1) // bs - 1, 0)
+        return (jnp.maximum(tables[b, jnp.minimum(j, last)], 0), 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, M),
+        in_specs=[pl.BlockSpec((1, H * C, lanes), q_map),
+                  pl.BlockSpec((1, bs, lanes), c_map)],
+        out_specs=pl.BlockSpec((1, H * C, v_lanes), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((H * C, 1), jnp.float32),
+            pltpu.VMEM((H * C, 1), jnp.float32),
+            pltpu.VMEM((H * C, v_lanes), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_latent_kernel, bs=bs, chunk=C, v_lanes=v_lanes,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H * C, v_lanes), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_latent_attention",
+    )(tables, starts, seq_end, qh, pool)
+    return out.reshape(B, H, C, v_lanes).transpose(0, 2, 1, 3)

@@ -38,8 +38,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.paged_attention.kernel import paged_attention_pallas
-from repro.kernels.paged_attention.ref import paged_attention_ref
+from repro.kernels.paged_attention.kernel import (
+    paged_attention_pallas, paged_latent_attention_pallas)
+from repro.kernels.paged_attention.ref import (paged_attention_ref,
+                                               paged_latent_attention_ref)
 
 KERNEL_KINDS = ("auto", "pallas", "ref")
 
@@ -84,6 +86,21 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     return paged_attention_pallas(
         q, k_pool, v_pool, block_tables, starts, n_valid,
         block_size=block_size, window=window, scale=scale,
+        interpret=_resolve_interpret(interpret))
+
+
+@partial(jax.jit, static_argnames=("block_size", "v_lanes", "scale",
+                                   "interpret"))
+def paged_latent_attention(q: jax.Array, pool: jax.Array,
+                           block_tables: jax.Array, starts: jax.Array,
+                           n_valid: jax.Array, *, block_size: int,
+                           v_lanes: int, scale: float,
+                           interpret: bool | None = None) -> jax.Array:
+    """(B,C,H,lanes) x latent pool -> (B,C,H,v_lanes): the latent
+    variant, ``paged_latent_attention_ref``'s signature."""
+    return paged_latent_attention_pallas(
+        q, pool, block_tables, starts, n_valid, block_size=block_size,
+        v_lanes=v_lanes, scale=scale,
         interpret=_resolve_interpret(interpret))
 
 

@@ -83,3 +83,36 @@ def paged_attention_ref(
     out = jnp.einsum("bkgst,btkd->bskgd", probs.astype(q.dtype),
                      v_all.astype(q.dtype))
     return out.reshape(B, C, H, D)
+
+
+def paged_latent_attention_ref(
+    q: jax.Array,                      # (B, C, H, lanes)
+    pool: jax.Array,                   # (N_blocks, block_size, lanes)
+    block_tables: jax.Array,           # (B, M) int32, -1 = unallocated
+    starts: jax.Array,                 # (B,) int32
+    n_valid: jax.Array,                # (B,) int32
+    *,
+    block_size: int,
+    v_lanes: int,
+    scale: float,
+) -> jax.Array:
+    """Dense latent (absorbed MLA) attention against the gathered logical
+    view: every head scores against each token's whole latent row and
+    averages its first ``v_lanes`` lanes. Returns (B, C, H, v_lanes);
+    columns ``>= n_valid[b]`` are garbage the caller discards."""
+    B, C, _, lanes = q.shape
+    M = block_tables.shape[1]
+    rows = (jnp.clip(block_tables, 0)[:, :, None] * block_size
+            + jnp.arange(block_size, dtype=jnp.int32)[None, None, :])
+    c_all = pool.reshape(-1, lanes)[rows.reshape(B, M * block_size)]
+    T = c_all.shape[1]
+    positions = starts[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+    kv_pos = jnp.arange(T, dtype=jnp.int32)
+    mask = ((positions[:, :, None] >= kv_pos[None, None, :])
+            & (kv_pos[None, None, :] < (starts + n_valid)[:, None, None]))
+    scores = jnp.einsum("bchl,btl->bhct", q, c_all.astype(q.dtype),
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(mask[:, None], scores, NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhct,btv->bchv", probs.astype(q.dtype),
+                      c_all[..., :v_lanes].astype(q.dtype))

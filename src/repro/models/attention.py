@@ -3,7 +3,8 @@
 All score math runs in float32; inputs/outputs stay in the compute dtype.
 Decode uses the KV caches from ``kvcache.py``; MLA decode uses the *absorbed*
 formulation (scores against the compressed cache — the memory-bound win that
-makes MLA decode viable at 32k).
+makes MLA decode viable at 32k), and so does paged MLA serving, for prefill
+chunks and decode rows alike (``mla_paged_attention``).
 """
 from __future__ import annotations
 
@@ -17,7 +18,7 @@ from repro.configs.base import AttentionConfig
 from repro.kernels import paged_attention as paged_kernel
 from repro.models.common import ParamBuilder, rms_norm
 from repro.models.kvcache import KVCache, MLACache, PagedKVCache, PagedLayout
-from repro.models.rope import apply_mrope, apply_rope
+from repro.models.rope import apply_mrope, apply_rope, yarn_mscale
 
 NEG_INF = -2.0 ** 30  # large-but-finite: keeps fully-masked rows NaN-free
 
@@ -353,6 +354,83 @@ def gqa_paged_attention(
 # MLA forward (deepseek-v2)
 # ---------------------------------------------------------------------------
 
+def mla_softmax_scale(a: AttentionConfig) -> float:
+    """``1 / sqrt(qk_nope + qk_rope)``, times YaRN's ``mscale(mscale_all_dim)``
+    squared where the config scales its rotary."""
+    scale = 1.0 / math.sqrt(a.qk_nope_head_dim + a.qk_rope_head_dim)
+    if a.yarn is not None and a.yarn.mscale_all_dim:
+        scale *= yarn_mscale(a.yarn.factor, a.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def mla_latent_lanes(a: AttentionConfig) -> int:
+    """Lanes of one token's latent row in the paged pool: the compressed kv
+    and the rotary key, zero-padded to whole 128-lane tiles (so the stacked
+    pool is written in place, ``model.pool_writes_in_place``)."""
+    return -(-(a.kv_lora_rank + a.qk_rope_head_dim) // 128) * 128
+
+
+def _mla_project(params, x: jax.Array, a: AttentionConfig,
+                 positions: jax.Array, norm_eps: float):
+    """q_nope (B,S,H,dn), rotated q_rope (B,S,H,dr), normed c_kv (B,S,r)
+    and the rotated shared k_rope (B,S,dr) of x (B,S,d)."""
+    dn, r = a.qk_nope_head_dim, a.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])         # (B,S,H,dn+dr)
+    q_nope = q[..., :dn]
+    q_rope = apply_rope(q[..., dn:], positions, a.rope_theta, yarn=a.yarn)
+    ckr = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])      # (B,S,r+dr)
+    c_kv = rms_norm(ckr[..., :r], params["kv_norm"], norm_eps)
+    k_rope = apply_rope(ckr[..., None, r:], positions, a.rope_theta,
+                        yarn=a.yarn)[:, :, 0]
+    return q_nope, q_rope, c_kv, k_rope
+
+
+def mla_paged_attention(
+    params,
+    x: jax.Array,                          # (B, C, d): C-token chunk per slot
+    a: AttentionConfig,
+    *,
+    pool: jax.Array,                       # (N_blocks, block_size, lanes)
+    layout: PagedLayout,
+    kernel: str = "auto",
+    norm_eps: float = 1e-6,
+) -> Tuple[jax.Array, jax.Array]:
+    """One serving step of absorbed MLA through a paged latent pool.
+
+    Each token's latent row ``[c_kv | k_rope | 0]`` scatters into the pool
+    (scope ``kv_pool``); each head's query is absorbed into the latent,
+    ``[q_nope . W_UK | q_rope | 0]``, so the kernel (``"pallas"``: the
+    latent kernel; ``"ref"``: gather-then-dense) scores it against the
+    rows and averages their compressed kv; ``W_UV`` per head and ``wo``
+    finish. Prefill chunks and decode rows take the same path. Returns
+    (y (B, C, d), the new pool).
+    """
+    B, C, _ = x.shape
+    H, r = a.num_heads, a.kv_lora_rank
+    positions = layout.token_positions(C)
+    q_nope, q_rope, c_kv, k_rope = _mla_project(params, x, a, positions,
+                                                norm_eps)
+    lanes = pool.shape[-1]
+    pad = lanes - r - a.qk_rope_head_dim
+    with jax.named_scope("kv_pool"):
+        row = jnp.concatenate(
+            [c_kv, k_rope, jnp.zeros((B, C, pad), x.dtype)], axis=-1)
+        pool = layout.write(pool, row)
+    q_abs = jnp.einsum("bshn,rhn->bshr", q_nope, params["w_uk"])
+    q_lat = jnp.concatenate(
+        [q_abs, q_rope.astype(x.dtype), jnp.zeros((B, C, H, pad), x.dtype)],
+        axis=-1)
+    fn = (paged_kernel.paged_latent_attention
+          if paged_kernel.resolve_kernel(kernel) == "pallas"
+          else paged_kernel.paged_latent_attention_ref)
+    ctx = fn(q_lat.astype(x.dtype), pool, layout.block_tables, layout.starts,
+             layout.n_valid, block_size=layout.block_size, v_lanes=r,
+             scale=mla_softmax_scale(a))
+    out = jnp.einsum("bshr,rhv->bshv", ctx, params["w_uv"])
+    y = jnp.einsum("bshv,hvd->bsd", out, params["wo"])
+    return y, pool
+
+
 def mla_attention(
     params,
     x: jax.Array,
@@ -364,21 +442,14 @@ def mla_attention(
     norm_eps: float = 1e-6,
 ) -> Tuple[jax.Array, Optional[MLACache]]:
     B, S, _ = x.shape
-    H = a.num_heads
-    dn, dr, dv, r = a.qk_nope_head_dim, a.qk_rope_head_dim, a.v_head_dim, a.kv_lora_rank
+    H, dr = a.num_heads, a.qk_rope_head_dim
     if positions is None:
         offset = cache.length if cache is not None else jnp.int32(0)
         positions = jnp.arange(S, dtype=jnp.int32)[None, :] + offset
 
-    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"])         # (B,S,H,dn+dr)
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, a.rope_theta)
-
-    ckr = jnp.einsum("bsd,dr->bsr", x, params["w_dkv"])      # (B,S,r+dr)
-    c_kv = rms_norm(ckr[..., :r], params["kv_norm"], norm_eps)
-    k_rope = apply_rope(ckr[..., None, r:], positions, a.rope_theta)[:, :, 0]  # (B,S,dr)
-
-    scale = 1.0 / math.sqrt(dn + dr)
+    q_nope, q_rope, c_kv, k_rope = _mla_project(params, x, a, positions,
+                                                norm_eps)
+    scale = mla_softmax_scale(a)
     new_cache = None
     if cache is not None and S == 1:
         # Absorbed decode: score against the compressed cache directly —

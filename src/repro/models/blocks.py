@@ -100,10 +100,10 @@ def init_block_cache(bt: str, cfg: ModelConfig, batch: int, max_len: int,
 # paged cache init (serving pool; GQA block types only)
 # ---------------------------------------------------------------------------
 
-# Block types whose cache is plain GQA k/v — the ones the paged serving
-# subsystem supports (ISSUE 2: GQA first; MLA/SSM/xLSTM archs stay on the
-# contiguous Server).
-PAGED_BLOCK_TYPES = ("attn_full", "attn_local", "attn_moe")
+# Block types the paged serving pool holds: GQA k/v leaves, or MLA's one
+# latent leaf (compressed kv and rotary key, ``attn.mla_latent_lanes``).
+PAGED_BLOCK_TYPES = ("attn_full", "attn_local", "attn_moe", "mla_dense",
+                     "mla_moe")
 
 # Block types whose per-request state is constant-size (conv history +
 # recurrent state, no seq-length axis) — the ones the recurrent serving
@@ -116,9 +116,12 @@ def init_paged_block_cache(bt: str, cfg: ModelConfig, num_blocks: int,
                            block_size: int, dtype=jnp.bfloat16) -> Dict[str, Any]:
     if bt not in PAGED_BLOCK_TYPES:
         raise ValueError(
-            f"paged serving supports GQA block types {PAGED_BLOCK_TYPES}, "
-            f"got {bt!r} — use the contiguous Server for this arch")
+            f"paged serving supports block types {PAGED_BLOCK_TYPES}, "
+            f"got {bt!r} — use cache='slots' for this arch")
     a = cfg.attention
+    if bt.startswith("mla"):
+        return {"latent": jnp.zeros(
+            (num_blocks, block_size, attn.mla_latent_lanes(a)), dtype)}
     shape = (num_blocks, block_size, a.num_kv_heads, a.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -244,7 +247,9 @@ def apply_block(
     # ---- FFN sub-layer ----
     h2 = rms_norm(x, params["ln2"], cfg.norm_eps)
     aux = zero
-    if bt.endswith("_moe"):
+    if bt.endswith("_moe") and cfg.moe.experts_held is not None:
+        y_ffn, _ = moe_mod.moe_ffn_held(params["moe"], h2, cfg.moe, cfg.act)
+    elif bt.endswith("_moe"):
         y_ffn, aux = moe_mod.moe_ffn(params["moe"], h2, cfg.moe, cfg.act,
                                      transport=moe_transport)
     else:
@@ -256,24 +261,46 @@ def _apply_block_paged(bt: str, params, x: jax.Array, cfg: ModelConfig,
                        cache: Cache, paged: PagedLayout,
                        moe_transport, paged_kernel="auto"
                        ) -> Tuple[jax.Array, Cache, jax.Array]:
-    """Paged-serving variant: GQA attention through the block pool.
+    """Paged-serving variant: attention through the block pool.
 
     Same residual structure as the contiguous path; only the attention
     sub-layer differs (pool scatter + the stash-resident kernel or its
-    gather-then-dense oracle instead of contiguous append).
+    gather-then-dense oracle instead of contiguous append). MLA blocks
+    write and read one latent leaf through the latent kernel; on a
+    multi-device mesh (``paged_kernel`` a sharded GQA callable) it runs
+    unsharded, as the latent has no head axis to split.
+
+    An expert layer that holds a share of the experts
+    (``MoEConfig.experts_held``) returns its counters (``moe.moe_ffn_held``)
+    in the aux slot; the step reports them (``runtime.steps``).
     """
     if bt not in PAGED_BLOCK_TYPES:
         raise ValueError(f"block type {bt!r} has no paged path")
     a = cfg.attention
-    window = a.sliding_window if bt.endswith("_local") else None
     with jax.named_scope("attention"):
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        pkv = PagedKVCache(cache["k"], cache["v"], paged.block_size)
-        y_attn, npkv = attn.gqa_paged_attention(params["attn"], h, a,
-                                                cache=pkv, layout=paged,
-                                                window=window,
-                                                kernel=paged_kernel)
+        if bt.startswith("mla"):
+            kind = "pallas" if callable(paged_kernel) else paged_kernel
+            y_attn, pool = attn.mla_paged_attention(
+                params["attn"], h, a, pool=cache["latent"], layout=paged,
+                kernel=kind, norm_eps=cfg.norm_eps)
+            new_cache = {"latent": pool}
+        else:
+            window = a.sliding_window if bt.endswith("_local") else None
+            pkv = PagedKVCache(cache["k"], cache["v"], paged.block_size)
+            y_attn, npkv = attn.gqa_paged_attention(params["attn"], h, a,
+                                                    cache=pkv, layout=paged,
+                                                    window=window,
+                                                    kernel=paged_kernel)
+            new_cache = {"k": npkv.k_pool, "v": npkv.v_pool}
         x = x + y_attn
+    if bt.endswith("_moe") and cfg.moe.experts_held is not None:
+        with jax.named_scope("moe"):
+            h2 = rms_norm(x, params["ln2"], cfg.norm_eps)
+            y_ffn, aux = moe_mod.moe_ffn_held(
+                params["moe"], h2, cfg.moe, cfg.act,
+                token_mask=paged.token_valid(x.shape[1]))
+        return x + y_ffn, new_cache, aux
     with jax.named_scope("mlp"):
         h2 = rms_norm(x, params["ln2"], cfg.norm_eps)
         if bt.endswith("_moe"):
@@ -287,4 +314,4 @@ def _apply_block_paged(bt: str, params, x: jax.Array, cfg: ModelConfig,
             y_ffn = mlp_mod.mlp(params["mlp"], h2, cfg.act, cfg.mlp_gated)
             aux = jnp.zeros((), jnp.float32)
         x = x + y_ffn
-    return x, {"k": npkv.k_pool, "v": npkv.v_pool}, aux
+    return x, new_cache, aux

@@ -130,6 +130,27 @@ class PagedLayout(NamedTuple):
         return (jnp.arange(chunk, dtype=jnp.int32)[None, :]
                 < self.n_valid[:, None])
 
+    def dest_rows(self, num_blocks: int, chunk: int) -> jax.Array:
+        """Flat pool-row index per (request, token column); out of bounds
+        (``num_blocks * block_size``) where the column is not valid."""
+        bs = self.block_size
+        pos = self.token_positions(chunk)                      # (B, C)
+        blk_idx = jnp.clip(pos // bs, 0, self.block_tables.shape[1] - 1)
+        blk = jnp.take_along_axis(self.block_tables, blk_idx, axis=1)
+        rows = blk * bs + pos % bs
+        return jnp.where(self.token_valid(chunk) & (blk >= 0), rows,
+                         num_blocks * bs)
+
+    def write(self, pool: jax.Array, new: jax.Array) -> jax.Array:
+        """Scatter ``new`` (B, C, ...) into ``pool`` (N_blocks, block_size,
+        ...) at the tokens' logical positions; invalid columns (beyond
+        ``n_valid``) are dropped."""
+        rows = self.dest_rows(pool.shape[0], new.shape[1]).reshape(-1)
+        tail = pool.shape[2:]
+        flat = pool.reshape(-1, *tail).at[rows].set(
+            new.reshape(-1, *tail).astype(pool.dtype), mode="drop")
+        return flat.reshape(pool.shape)
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
@@ -157,31 +178,12 @@ class PagedKVCache:
         return PagedKVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
                             block_size)
 
-    def _dest_rows(self, layout: PagedLayout, chunk: int) -> jax.Array:
-        """Flat pool-row index per (request, token column); OOB when invalid."""
-        bs = self.block_size
-        pos = layout.token_positions(chunk)                    # (B, C)
-        blk_idx = jnp.clip(pos // bs, 0, layout.block_tables.shape[1] - 1)
-        blk = jnp.take_along_axis(layout.block_tables, blk_idx, axis=1)
-        rows = blk * bs + pos % bs
-        oob = self.num_blocks * bs                             # dropped by .at
-        return jnp.where(layout.token_valid(chunk) & (blk >= 0), rows, oob)
-
     def write(self, k_new: jax.Array, v_new: jax.Array,
               layout: PagedLayout) -> "PagedKVCache":
         """Scatter (B, C, K, D) new tokens into the pool at their logical
         positions; invalid columns (beyond ``n_valid``) are dropped."""
-        chunk = k_new.shape[1]
-        rows = self._dest_rows(layout, chunk).reshape(-1)
-        tail = self.k_pool.shape[2:]
-        flat_k = self.k_pool.reshape(-1, *tail)
-        flat_v = self.v_pool.reshape(-1, *tail)
-        flat_k = flat_k.at[rows].set(
-            k_new.reshape(-1, *tail).astype(flat_k.dtype), mode="drop")
-        flat_v = flat_v.at[rows].set(
-            v_new.reshape(-1, *tail).astype(flat_v.dtype), mode="drop")
-        return PagedKVCache(flat_k.reshape(self.k_pool.shape),
-                            flat_v.reshape(self.v_pool.shape),
+        return PagedKVCache(layout.write(self.k_pool, k_new),
+                            layout.write(self.v_pool, v_new),
                             self.block_size)
 
     def gather(self, block_tables: jax.Array,

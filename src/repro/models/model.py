@@ -165,11 +165,12 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                      dtype=jnp.bfloat16) -> PyTree:
     """Paged serving cache: one (num_blocks, block_size, K, D) k/v pool per
-    layer, mirroring the group structure. One *logical* block id indexes the
-    same slot in every layer's pool, so the scheduler tracks a single block
-    table per request. No length scalar: per-request lengths live host-side
-    in the scheduler (``repro.engine.Engine``). Raises for non-GQA
-    architectures.
+    layer (MLA: one (num_blocks, block_size, lanes) latent pool), mirroring
+    the group structure. One *logical* block id indexes the same slot in
+    every layer's pool, so the scheduler tracks a single block table per
+    request. No length scalar: per-request lengths live host-side in the
+    scheduler (``repro.engine.Engine``). Raises for block types the pool
+    cannot hold (``blocks.PAGED_BLOCK_TYPES``).
     """
     groups = []
     for pattern, repeats in layer_plan(cfg):
@@ -188,9 +189,11 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 def pool_writes_in_place(pool: PyTree) -> bool:
     """Whether a stacked paged-pool group is written through its flat view.
 
-    Merging the leading (L, N) axes of an (L, N, bs, K, D) leaf is free only
-    where the device keeps the leaf row-major. The TPU does so when (K, D)
-    fill whole (8, 128) tiles; for D=96 or 64 its default layout puts the
+    Merging the leading (L, N) axes of an (L, N, bs, K, D) leaf, or of an
+    MLA latent leaf (L, N, bs, lanes), is free only where the device keeps
+    the leaf row-major. The TPU does so when the last two dims fill whole
+    (8, 128) tiles (the latent's lanes are padded to 128s, its bs rows are
+    the tile's rows); for D=96 or 64 its default layout puts the
     block axis N innermost (Phi-3's pool: ``bf16[32,1017,16,32,96]
     {1,4,3,2,0}``), the flat view becomes a transposing copy of the pool, and
     the per-layer slice path is the cheaper one (PERF.md §5).

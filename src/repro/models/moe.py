@@ -22,14 +22,14 @@ from repro.models.common import ParamBuilder, act_fn
 
 class RouteResult(NamedTuple):
     expert_ids: jax.Array    # (N, k) int32
-    gates: jax.Array         # (N, k) f32, normalized over k
+    gates: jax.Array         # (N, k) f32 (``MoEConfig.norm_topk_prob``)
     aux_loss: jax.Array      # () load-balance aux
     z_loss: jax.Array        # () router z-loss
 
 
 def init_moe(b: ParamBuilder, d_model: int, m: MoEConfig) -> None:
     b.param("router", (d_model, m.num_experts), ("embed", "expert"))
-    e = m.num_experts
+    e = m.num_held
     b.param("w_gate", (e, d_model, m.expert_ff), ("expert", "embed", "moe_ff"), fan_in=d_model)
     b.param("w_up", (e, d_model, m.expert_ff), ("expert", "embed", "moe_ff"), fan_in=d_model)
     b.param("w_down", (e, m.expert_ff, d_model), ("expert", "moe_ff", "embed"), fan_in=m.expert_ff)
@@ -46,7 +46,10 @@ def route_topk(x: jax.Array, router_w: jax.Array, m: MoEConfig) -> RouteResult:
                         router_w.astype(jnp.float32))
     probs = jax.nn.softmax(logits, axis=-1)
     gates, ids = jax.lax.top_k(probs, m.top_k)               # (N,k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if m.routed_scaling_factor != 1.0:
+        gates = gates * m.routed_scaling_factor
     # load-balance: E * sum_e (frac tokens to e) * (mean prob of e)
     e = m.num_experts
     one_hot = jax.nn.one_hot(ids[:, 0], e, dtype=jnp.float32)  # primary expert
@@ -70,6 +73,13 @@ def expert_ffn(w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
     u = jnp.einsum("ecd,edf->ecf", x, w_up)
     h = act_fn(act)(g) * u
     return jnp.einsum("ecf,efd->ecd", h, w_down)
+
+
+def shared_expert_ffn(params, xf: jax.Array, act: str = "silu") -> jax.Array:
+    """The always-on shared experts, one gated FFN over xf (N, d)."""
+    g = jnp.einsum("nd,df->nf", xf, params["ws_gate"])
+    u = jnp.einsum("nd,df->nf", xf, params["ws_up"])
+    return jnp.einsum("nf,fd->nd", act_fn(act)(g) * u, params["ws_down"])
 
 
 def build_dispatch(ids: jax.Array, gates: jax.Array, n_experts: int,
@@ -130,10 +140,50 @@ def moe_ffn_oracle(params, x: jax.Array, m: MoEConfig, act: str = "silu",
     w = (gates * keep).astype(x.dtype)
     y = jnp.einsum("nkd,nk->nd", gathered, w)
     if m.num_shared > 0:
-        g = jnp.einsum("nd,df->nf", xf, params["ws_gate"])
-        u = jnp.einsum("nd,df->nf", xf, params["ws_up"])
-        y = y + jnp.einsum("nf,fd->nd", act_fn(act)(g) * u, params["ws_down"])
+        y = y + shared_expert_ffn(params, xf, act)
     return y.reshape(b, s, d), r.aux_loss + r.z_loss
+
+
+def moe_ffn_held(params, x: jax.Array, m: MoEConfig, act: str = "silu",
+                 token_mask: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The expert layer of one expert-parallel share, dropping no token.
+
+    x: (B, S, d). The router scores all ``num_experts``; of each token's
+    top-k picks only those of the held experts ``[lo, hi) =
+    m.experts_held`` are computed here (a masked einsum over the held
+    experts: every expert's weights are read once, and no token waits on
+    another for room), plus the shared experts whole. What the other
+    shares' experts add is theirs to compute. Tokens outside
+    ``token_mask`` (B, S) contribute nothing and are not counted.
+
+    Returns (out (B, S, d), counters (3,) float32): the top-k picks of the
+    fed tokens, those that go to held experts, and the most picks one held
+    expert took.
+    """
+    b, s, d = x.shape
+    xf = x.reshape(-1, d)
+    lo, hi = m.experts_held
+    with jax.named_scope("moe.router"):
+        r = route_topk(xf, params["router"], m)
+        valid = (jnp.ones((xf.shape[0],), bool) if token_mask is None
+                 else token_mask.reshape(-1))
+        local = r.expert_ids - lo                                # (N, k)
+        here = (local >= 0) & (local < hi - lo) & valid[:, None]
+        picks = jax.nn.one_hot(jnp.where(here, local, -1), hi - lo,
+                               dtype=jnp.float32)                # (N, k, E)
+        w = jnp.einsum("nk,nke->en", r.gates, picks)             # (E, N)
+        counters = jnp.stack([valid.sum() * m.top_k, here.sum(),
+                              picks.sum((0, 1)).max()]).astype(jnp.float32)
+    with jax.named_scope("moe.experts"):
+        g = jnp.einsum("nd,edf->enf", xf, params["w_gate"])
+        u = jnp.einsum("nd,edf->enf", xf, params["w_up"])
+        h = act_fn(act)(g) * u * w[:, :, None].astype(x.dtype)
+        y = jnp.einsum("enf,efd->nd", h, params["w_down"])
+    if m.num_shared > 0:
+        with jax.named_scope("moe.shared"):
+            y = y + shared_expert_ffn(params, xf, act)
+    return y.reshape(b, s, d), counters
 
 
 MoETransport = Callable[..., Tuple[jax.Array, jax.Array]]

@@ -1,6 +1,8 @@
-"""Rotary position embeddings: standard, partial (stablelm), and M-RoPE (qwen2-vl)."""
+"""Rotary position embeddings: standard, partial (stablelm), YaRN-scaled
+(deepseek-v2) and M-RoPE (qwen2-vl)."""
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -17,23 +19,58 @@ def rope_freqs(head_dim: int, theta: float, rotary_pct: float = 1.0) -> jax.Arra
     return 1.0 / (theta ** exponent)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, yarn) -> jax.Array:
+    """YaRN inverse frequencies of a ``dim``-wide rotary head: slots that
+    turn more than ``beta_fast`` times over the original context keep their
+    frequency, slots that turn fewer than ``beta_slow`` times are divided by
+    ``factor``, and a linear ramp joins the two."""
+    def corr_dim(rotations: float) -> float:
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
 def _rotate_half(x: jax.Array) -> jax.Array:
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([-x2, x1], axis=-1)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               rotary_pct: float = 1.0) -> jax.Array:
-    """Apply RoPE. x: (..., S, H, D); positions: broadcastable to (..., S)."""
+               rotary_pct: float = 1.0, yarn=None) -> jax.Array:
+    """Apply RoPE. x: (..., S, H, D); positions: broadcastable to (..., S).
+    ``yarn`` (a ``configs.base.YarnConfig``) scales the whole head's
+    frequencies and magnitude."""
     head_dim = x.shape[-1]
-    inv = rope_freqs(head_dim, theta, rotary_pct)
+    scale = 1.0
+    if yarn is not None:
+        inv = yarn_inv_freq(head_dim, theta, yarn)
+        scale = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    else:
+        inv = rope_freqs(head_dim, theta, rotary_pct)
     rot_dim = 2 * inv.shape[0]
     if rot_dim == 0:
         return x
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., S, rot/2)
     ang = jnp.concatenate([ang, ang], axis=-1)               # (..., S, rot)
-    cos = jnp.cos(ang)[..., :, None, :]                      # (..., S, 1, rot)
-    sin = jnp.sin(ang)[..., :, None, :]
+    cos = jnp.cos(ang)[..., :, None, :] * scale              # (..., S, 1, rot)
+    sin = jnp.sin(ang)[..., :, None, :] * scale
     x_rot, x_pass = x[..., :rot_dim], x[..., rot_dim:]
     x_f = x_rot.astype(jnp.float32)
     out = x_f * cos + _rotate_half(x_f) * sin

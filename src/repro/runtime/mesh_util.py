@@ -181,21 +181,23 @@ def paged_cache_spec_tree(cache_shapes: PyTree, rules: Rules,
     leading layer-stack dim — K is always dim -2. There is no batch dim to
     put on the dp axes (the pool is shared by every request), so head
     sharding is the only axis: decode attention then stays collective-free
-    per step, exactly like the contiguous cache's kv-head sharding.
+    per step, exactly like the contiguous cache's kv-head sharding. MLA's
+    ``latent`` leaves (num_blocks, block_size, lanes) have no head axis
+    and stay replicated.
     """
     sizes = _axis_sizes(mesh)
     tp = rules.tp_axis
 
-    def one(x) -> P:
+    def one(path, x) -> P:
         shape = x.shape
-        if len(shape) < 4:
-            return P(*([None] * len(shape)))
         entries: list = [None] * len(shape)
-        if tp in sizes and sizes[tp] > 1 and shape[-2] % sizes[tp] == 0:
+        latent = bool(path) and getattr(path[-1], "key", None) == "latent"
+        if (len(shape) >= 4 and not latent and tp in sizes and sizes[tp] > 1
+                and shape[-2] % sizes[tp] == 0):
             entries[-2] = tp
         return P(*entries)
 
-    return jax.tree.map(one, cache_shapes)
+    return jax.tree_util.tree_map_with_path(one, cache_shapes)
 
 
 def cache_spec_tree(cache_shapes: PyTree, rules: Rules, mesh: Mesh,

@@ -409,6 +409,12 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
     (``model.pool_writes_in_place``); the rest copy their pool slice out
     and back.
 
+    Where the expert layers hold a share of the experts
+    (``MoEConfig.experts_held``, ``meta["moe_counters"]``), ``emit="last"``
+    also returns the step's expert counters, int32 (3,), summed over the
+    expert layers: the fed tokens' top-k picks, those that go to held
+    experts, and the most picks one held expert took in a layer.
+
     MoE archs on a >1-shard tensor axis serve through the token-mask-aware
     jam transports: the padding-column mask from ``PagedLayout.token_valid``
     threads into ``core.dispatch``'s shard bodies so padding can never
@@ -435,9 +441,15 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
     constrain = act_constrain(
         rules, mesh, slots % mesh_util.dp_extent(rules, mesh) == 0)
 
+    # an expert layer that holds a share of the experts reports its
+    # counters through forward's aux (blocks._apply_block_paged); the
+    # tick's step returns them beside the tokens, fetched with them
+    moe_counters = (emit == "last" and cfg.moe is not None
+                    and cfg.moe.experts_held is not None)
+
     def paged_step(params, cache, tokens, block_tables, starts, n_valid):
         layout = PagedLayout(block_tables, starts, n_valid, block_size)
-        logits, new_cache, _ = model_lib.forward(
+        logits, new_cache, aux = model_lib.forward(
             cfg, params, tokens, cache=cache, paged=layout,
             paged_kernel=kernel_fn,
             moe_transport=transport, constrain=constrain)
@@ -452,6 +464,8 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
             last_logits = jnp.take_along_axis(
                 logits, last[:, None, None], axis=1)[:, 0]    # (slots, V)
             next_tok = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
+        if moe_counters:
+            return next_tok, new_cache, aux.astype(jnp.int32)
         return next_tok, new_cache
 
     cache_shapes = jax.eval_shape(
@@ -475,7 +489,7 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
     return StepBundle(
         fn=paged_step,
         in_shardings=in_sh,
-        out_shardings=(rep, cache_shard),
+        out_shardings=(rep, cache_shard) + ((rep,) if moe_counters else ()),
         abstract_inputs=abstract,
         meta=dict(rules=rules, pspecs=pspecs, axes=axes,
                   kind={"last": "paged_decode", "all": "paged_verify",
@@ -485,7 +499,7 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
                   num_blocks=num_blocks, chunk=chunk, slots=slots,
                   paged_kernel=paged_kernel, emit=emit,
                   kv_pool_in_place_layers=in_place_layers,
-                  kv_pool_layers=pool_layers),
+                  kv_pool_layers=pool_layers, moe_counters=moe_counters),
     )
 
 

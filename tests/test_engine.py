@@ -696,7 +696,7 @@ def test_default_cache_backend_per_family():
         "mamba-130m": "recurrent",       # pure SSM -> constant-size state
         "xlstm-1.3b": "recurrent",
         "hymba-1.5b": "slots",           # hybrid attn+SSM: pool can't hold
-        "deepseek-v2-lite-16b": "slots", # MLA latents
+        "deepseek-v2-lite-16b": "paged", # MLA latents: one pool leaf
         "qwen2-vl-72b": "slots",         # mrope position streams
     }
     for arch, want in expect.items():

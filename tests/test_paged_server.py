@@ -268,12 +268,14 @@ def test_kernel_pallas_identity_run(setup):
 
 
 def test_rejects_non_gqa_arch(setup):
+    """Hybrid attention+SSM blocks carry recurrent state beside their k/v,
+    which the pool cannot hold (MLA latents it can: tests/test_mla_paged.py)."""
     _, run, mesh, _ = setup
-    mla_cfg = get_smoke("deepseek-v2-lite-16b")
-    run_mla = dataclasses.replace(run, model=mla_cfg)
+    hybrid_cfg = get_smoke("hymba-1.5b")
+    run_hybrid = dataclasses.replace(run, model=hybrid_cfg)
     with pytest.raises(ValueError, match="paged serving supports"):
         with mesh:
-            Engine(mla_cfg, run_mla, mesh, cache="paged", slots=2,
+            Engine(hybrid_cfg, run_hybrid, mesh, cache="paged", slots=2,
                    max_len=32, num_blocks=8, block_size=4)
 
 

@@ -116,3 +116,43 @@ def test_paged_step_writes_lane_aligned_pool_in_place(
         assert copies == []
     else:
         assert "slice" in copies and "dynamic-update-slice" in copies
+
+
+def test_mla_step_holds_only_the_latent_kernel_and_writes_in_place(
+        topo, monkeypatch):
+    """DeepSeek-V2-Lite's block at its published widths, two expert layers
+    over a 640-lane latent pool: the compiled step's only Mosaic kernel is
+    the latent kernel, one call a layer (the held experts are XLA
+    matmuls), and no layer's pool is sliced out or written back."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "bench"))
+    import spec
+    from blocks import mla_moe
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    full = spec.read_json(os.path.join(os.path.dirname(__file__), "..",
+                                       "bench", "configs",
+                                       "deepseek-v2-lite-ep4.json"))
+    g = mla_moe.geometry(dict(full, num_hidden_layers=STEP_LAYERS,
+                              first_k_dense_replace=0), MAX_BLOCKS_PER_SEQ
+                         * BLOCK_SIZE)
+    cfg = mla_moe.model_config(g, "bfloat16")
+    run = RunConfig(model=cfg, shape=SHAPES["decode_32k"],
+                    sharding=ShardingConfig(fsdp_params=False, seq_axis=None))
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    with mesh:
+        b = make_paged_serve_step(
+            cfg, run, mesh, slots=SLOTS, chunk=DEFAULT_CHUNK,
+            num_blocks=STEP_BLOCKS, block_size=BLOCK_SIZE,
+            max_blocks_per_seq=MAX_BLOCKS_PER_SEQ, kernel="pallas")
+        hlo = jax.jit(b.fn, in_shardings=b.in_shardings,
+                      out_shardings=b.out_shardings, donate_argnums=(1,)
+                      ).lower(*b.abstract_inputs).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == STEP_LAYERS
+    assert all("paged_latent_attention" in line for line in calls)
+    assert "ragged" not in hlo
+    assert b.meta["kv_pool_in_place_layers"] == STEP_LAYERS
+    assert b.meta["moe_counters"]
+    assert _pool_copies(hlo, (STEP_BLOCKS, BLOCK_SIZE, 640)) == []

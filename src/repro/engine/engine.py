@@ -56,6 +56,7 @@ from repro.engine.state import (BlockPool, PagedKVState, RecurrentState,
                                 SequenceState, SlotKVState)
 from repro.engine.stream import RequestHandle
 from repro.faults.errors import EngineFailedError
+from repro.kernels.paged_attention import latent_kernel_counts
 from repro.models import model as model_lib
 from repro.models.kvcache import state_to_bytes
 from repro.runtime.steps import (make_paged_serve_step,
@@ -64,6 +65,8 @@ from repro.runtime.steps import (make_paged_serve_step,
 
 # Engine.metrics() keys of an expert-parallel share's step counters
 MOE_COUNTERS = ("moe_pairs", "moe_pairs_here", "moe_expert_pairs_max")
+LATENT_COUNTERS = ("latent_kv_steps", "latent_kv_steps_live",
+                   "latent_rows_fetched")
 
 PyTree = Any
 
@@ -238,6 +241,9 @@ class Engine:
         # expert-parallel share's counters, summed over the expert layers
         # and the ticks' steps (runtime.steps, meta["moe_counters"])
         self.moe_counts = dict.fromkeys(MOE_COUNTERS, 0)
+        # the latent kernel's grid steps and DMA'd pool rows, summed over
+        # its calls (MLA layers) and the ticks' steps, counted on the host
+        self.latent_counts = dict.fromkeys(LATENT_COUNTERS, 0)
         self.migrations_in = 0
         self.migrations_out = 0
         self._placements: Dict[str, str] = {}
@@ -284,6 +290,8 @@ class Engine:
             # the occupancy knob the stash-resident kernel's bytes-read win
             # scales with (docs/serving.md)
             self.paged_kernel: str = self.bundle.meta["paged_kernel"]
+            self._latent_calls = (self.bundle.meta["latent_layers"]
+                                  if self.paged_kernel == "pallas" else 0)
             self._live_frac_last = 0.0
             self._live_frac_sum = 0.0
             self._live_frac_ticks = 0
@@ -1020,6 +1028,12 @@ class Engine:
                     self.prefill_tokens += n
                 self.tokens_fed += n
             self.step_token_slots += self.slots * self.chunk
+            if paged and self._latent_calls:
+                counts = latent_kernel_counts(
+                    starts, n_valid, block_size=self.block_size,
+                    max_blocks=self.max_blocks_per_seq)
+                for k, v in zip(LATENT_COUNTERS, counts):
+                    self.latent_counts[k] += v * self._latent_calls
 
             args = [self.cache, jnp.asarray(tokens)]
             if paged:
@@ -1306,6 +1320,7 @@ class Engine:
                 "kv_pool_layers": self.bundle.meta["kv_pool_layers"],
                 **(self.moe_counts if self.bundle.meta["moe_counters"]
                    else {}),
+                **(self.latent_counts if self._latent_calls else {}),
                 "live_token_fraction": self._live_frac_last,
                 "live_token_fraction_mean": (
                     self._live_frac_sum / self._live_frac_ticks

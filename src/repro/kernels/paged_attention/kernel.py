@@ -42,8 +42,19 @@ and idle rows (``n_valid == 0``, which touch zero blocks).
 
 Latent variant (``paged_latent_attention_pallas``, absorbed MLA): MQA over a
 ``(N, bs, lanes)`` pool of latent rows, one shared "head" that is the key
-over all its lanes and the value over its first ``v_lanes``; same grid,
-tables and early exit, one DMA a block for both products.
+over all its lanes and the value over its first ``v_lanes``. Its grid is
+``(B, ceil(M / P))``: a grid step covers P table entries of a row
+(``latent_blocks_per_step``, 16 at bs=16: 256 tokens), so each step's
+products are ``(H*C, lanes) x (lanes, P*bs)`` and ``(H*C, P*bs) x (P*bs,
+v_lanes)`` rather than a 16-wide sliver of the MXU. The pool stays in HBM
+(``memory_space=pl.ANY``); the body gathers the step's blocks itself, one
+DMA of ``(bs, lanes)`` a live allocated entry, into one of two VMEM
+buffers ``(2, P, bs, lanes)``. Before it waits on its own copies, each live
+step starts those of the next live step into the other buffer: the row's
+next step, else the first step of the next row with tokens, so the
+prefetch crosses rows and both grid axes run in order. Dead steps (past
+``ceil(seq_end / bs)`` blocks) start and compute nothing; entries past M
+or of -1 are never copied, and their columns are masked.
 """
 from __future__ import annotations
 
@@ -200,34 +211,111 @@ def paged_attention_pallas(
 # latent variant (MLA): one shared latent "head" serves as k and v
 # ---------------------------------------------------------------------------
 
-def _latent_kernel(tables_ref, starts_ref, seq_end_ref, q_ref, c_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, bs: int, chunk: int, v_lanes: int,
+# Pool tokens one grid step of the latent kernel gathers: both products
+# then span two 128-wide MXU tiles, and the double buffer holds ~640 KiB of
+# VMEM at 640 lanes in bf16. 128 and 512 are untimed (PERF.md §6).
+LATENT_STEP_TOKENS = 256
+
+
+def latent_blocks_per_step(max_blocks: int, block_size: int) -> int:
+    """P, the block-table entries one grid step of the latent kernel
+    covers: ``LATENT_STEP_TOKENS`` worth of blocks, at most ``max_blocks``."""
+    return max(1, min(LATENT_STEP_TOKENS // block_size, max_blocks))
+
+
+def _latent_kernel(tables_ref, starts_ref, seq_end_ref, q_ref, pool_ref,
+                   o_ref, m_ref, l_ref, acc_ref, buf_ref, sem_ref, dma_ref,
+                   *, bs: int, chunk: int, per_step: int, v_lanes: int,
                    scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    hc = q_ref.shape[1]                           # H * C rows
+    i = pl.program_id(1)
+    ni = pl.num_programs(1)
+    rows, max_blocks = tables_ref.shape
+    hc = q_ref.shape[1]                           # H * C query rows
+    P = per_step
 
-    @pl.when(j == 0)
+    def n_live(row):                              # blocks resident after the step
+        return (seq_end_ref[row] + bs - 1) // bs
+
+    def entry(row, j):
+        # table entry j of the row, and whether a copy fetches it: live and
+        # allocated, so never an entry past M nor a -1
+        blk = tables_ref[row, jnp.minimum(j, max_blocks - 1)]
+        return blk, (j < n_live(row)) & (blk >= 0)
+
+    def copies(row, step, slot, op):
+        # start (or wait for) grid step (row, step)'s copies into buffer
+        # `slot`: one DMA of bs latent rows a fetched entry
+        for k in range(P):
+            blk, fetched = entry(row, step * P + k)
+            copy = pltpu.make_async_copy(pool_ref.at[jnp.maximum(blk, 0)],
+                                         buf_ref.at[slot, k],
+                                         sem_ref.at[slot, k])
+            pl.when(fetched)(getattr(copy, op))
+
+    @pl.when((b == 0) & (i == 0))
+    def _first():
+        dma_ref[0] = 0                            # buffer of the next live step
+        dma_ref[1] = 0                            # whether its copies started
+        # slots no copy fills are masked, but take p = 0 into p·c: zeros
+        # keep uninitialised VMEM (a NaN) out of the accumulator
+        buf_ref[...] = jnp.zeros_like(buf_ref)
+
+    @pl.when(i == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -1e30)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    start = starts_ref[b]
-    seq_end = seq_end_ref[b]
+    @pl.when(i * P < n_live(b))                   # dead steps do nothing
+    def _step():
+        slot = dma_ref[0]
 
-    @pl.when(j < (seq_end + bs - 1) // bs)
-    def _block():
+        @pl.when(dma_ref[1] == 0)                 # the call's first live step
+        def _():
+            copies(b, i, slot, "start")
+
+        # start the next live step's copies into the other buffer: this
+        # row's next step, else the first step of the next row with tokens
+        dma_ref[0] = 1 - slot
+        dma_ref[1] = 1
+        more = (i + 1) * P < n_live(b)
+
+        @pl.when(more)
+        def _():
+            copies(b, i + 1, 1 - slot, "start")
+
+        @pl.when(jnp.logical_not(more))
+        def _():
+            nxt = jax.lax.fori_loop(
+                b + 1, rows, lambda r, f: jnp.where(
+                    (f == rows) & (seq_end_ref[r] > 0), r, f), rows)
+
+            @pl.when(nxt < rows)
+            def _():
+                copies(nxt, 0, 1 - slot, "start")
+            dma_ref[1] = (nxt < rows).astype(jnp.int32)
+
+        # column t is token i·P·bs + t, of table entry i·P + t // bs; the
+        # columns of a -1 entry are masked like those past seq_end, on one
+        # row of columns before it is broadcast over the query rows
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, P * bs), 1)
+        entry_of = jax.lax.div(col, bs)
+        k_pos = i * (P * bs) + col
+        keep = k_pos < seq_end_ref[b]
+        for k in range(P):
+            blk, _ = entry(b, i * P + k)
+            keep &= entry_of != jnp.where(blk < 0, k, -1)
         # row r = h * C + c serves chunk column c = r % C of head h
-        q_pos = start + jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, (hc, bs), 0), chunk)
-        k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (hc, bs), 1)
-        mask = (q_pos >= k_pos) & (k_pos < seq_end)
-        c = c_ref[0]                              # (bs, lanes)
+        q_pos = starts_ref[b] + jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (hc, P * bs), 0), chunk)
+        mask = (q_pos >= k_pos) & keep
+
+        copies(b, i, slot, "wait")
+        c = buf_ref[slot].reshape(P * bs, buf_ref.shape[-1])  # (P*bs, lanes)
         s = jax.lax.dot_general(
             q_ref[0], c, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale   # (H*C, bs)
+            preferred_element_type=jnp.float32) * scale      # (H*C, P*bs)
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
@@ -239,7 +327,7 @@ def _latent_kernel(tables_ref, starts_ref, seq_end_ref, q_ref, c_ref, o_ref,
             p.astype(c.dtype), c[:, :v_lanes], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == nj - 1)
+    @pl.when(i == ni - 1)
     def _flush():
         o_ref[0] = (acc_ref[...]
                     / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
@@ -263,46 +351,47 @@ def paged_latent_attention_pallas(
     Every query head scores against one latent row per token (all
     ``lanes``: the compressed kv, the rotary key and any zero padding, which
     the query pads with zeros too) and averages the row's first
-    ``v_lanes`` lanes (the compressed kv). One grid step DMAs one pool
-    block ``(1, bs, lanes)`` once and uses it for both products. Grid,
-    block tables and the clamping of dead steps are those of
-    ``paged_attention_pallas``.
+    ``v_lanes`` lanes (the compressed kv). A grid step gathers P =
+    ``latent_blocks_per_step(M, block_size)`` pool blocks of a row by DMA
+    through the block table, double-buffered across live steps, and uses
+    them for both products.
     """
     B, C, H, lanes = q.shape
     bs = block_size
     assert pool.shape[1:] == (bs, lanes), (pool.shape, bs, lanes)
     M = block_tables.shape[1]
+    P = latent_blocks_per_step(M, bs)
     qh = q.transpose(0, 2, 1, 3).reshape(B, H * C, lanes)
     tables = block_tables.astype(jnp.int32)
     starts = starts.astype(jnp.int32)
     seq_end = starts + n_valid.astype(jnp.int32)
 
-    def q_map(b, j, tables, starts, seq_end):
+    def q_map(b, i, tables, starts, seq_end):
         return (b, 0, 0)
-
-    def c_map(b, j, tables, starts, seq_end):
-        last = jnp.maximum((seq_end[b] + bs - 1) // bs - 1, 0)
-        return (jnp.maximum(tables[b, jnp.minimum(j, last)], 0), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, M),
+        grid=(B, pl.cdiv(M, P)),
         in_specs=[pl.BlockSpec((1, H * C, lanes), q_map),
-                  pl.BlockSpec((1, bs, lanes), c_map)],
+                  pl.BlockSpec(memory_space=pl.ANY)],   # the pool stays in HBM
         out_specs=pl.BlockSpec((1, H * C, v_lanes), q_map),
         scratch_shapes=[
             pltpu.VMEM((H * C, 1), jnp.float32),
             pltpu.VMEM((H * C, 1), jnp.float32),
             pltpu.VMEM((H * C, v_lanes), jnp.float32),
+            pltpu.VMEM((2, P, bs, lanes), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, P)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_latent_kernel, bs=bs, chunk=C, v_lanes=v_lanes,
-                          scale=scale),
+        functools.partial(_latent_kernel, bs=bs, chunk=C, per_step=P,
+                          v_lanes=v_lanes, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H * C, v_lanes), q.dtype),
+        # the prefetch crosses rows, so both axes run in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_latent_attention",
     )(tables, starts, seq_end, qh, pool)

@@ -35,11 +35,13 @@ from typing import Callable, Optional, Sequence, Tuple
 import jax
 import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.paged_attention.kernel import (
-    paged_attention_pallas, paged_latent_attention_pallas)
+    latent_blocks_per_step, paged_attention_pallas,
+    paged_latent_attention_pallas)
 from repro.kernels.paged_attention.ref import (paged_attention_ref,
                                                paged_latent_attention_ref)
 
@@ -102,6 +104,22 @@ def paged_latent_attention(q: jax.Array, pool: jax.Array,
         q, pool, block_tables, starts, n_valid, block_size=block_size,
         v_lanes=v_lanes, scale=scale,
         interpret=_resolve_interpret(interpret))
+
+
+def latent_kernel_counts(starts, n_valid, *, block_size: int,
+                         max_blocks: int) -> Tuple[int, int, int]:
+    """What one call of the latent kernel does for a step's rows, counted
+    on the host from each row's ``(start, n)``: (grid steps, live grid
+    steps, pool rows DMA'd). A row's live steps are those that cover its
+    ``ceil((start + n) / block_size)`` resident blocks, ``P`` table entries
+    a step (``latent_blocks_per_step``); each of those blocks is one DMA of
+    ``block_size`` rows, as every resident block of an Engine's row is
+    allocated."""
+    per_step = latent_blocks_per_step(max_blocks, block_size)
+    live = -(-(np.asarray(starts) + np.asarray(n_valid)) // block_size)
+    steps = len(live) * -(-max_blocks // per_step)
+    return (steps, int((-(-live // per_step)).sum()),
+            int(live.sum()) * block_size)
 
 
 def sharded_paged_specs(mesh: Mesh, *, batch: int, kv_heads: int,

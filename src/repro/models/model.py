@@ -213,6 +213,14 @@ def paged_in_place_layers(cfg: ModelConfig, cache: PyTree) -> Tuple[int, int]:
     return in_place, total
 
 
+def paged_latent_layers(cfg: ModelConfig, cache: PyTree) -> int:
+    """Layers whose paged pool is an MLA latent leaf: the latent kernel's
+    calls a step."""
+    return sum(repeats
+               for (_, repeats), group in zip(layer_plan(cfg), cache["groups"])
+               for one in group if "latent" in one)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------

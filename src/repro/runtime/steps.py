@@ -407,7 +407,8 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
     ``meta["kv_pool_in_place_layers"]`` of ``meta["kv_pool_layers"]``
     layers scatter their new k/v straight into the donated pool
     (``model.pool_writes_in_place``); the rest copy their pool slice out
-    and back.
+    and back. ``meta["latent_layers"]`` counts the MLA layers, whose pool is
+    one latent leaf.
 
     Where the expert layers hold a share of the experts
     (``MoEConfig.experts_held``, ``meta["moe_counters"]``), ``emit="last"``
@@ -472,6 +473,7 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
         lambda: model_lib.init_paged_cache(cfg, num_blocks, block_size))
     in_place_layers, pool_layers = model_lib.paged_in_place_layers(
         cfg, cache_shapes)
+    latent_layers = model_lib.paged_latent_layers(cfg, cache_shapes)
     cache_shard = jax.tree.map(
         lambda s: NamedSharding(mesh, s),
         mesh_util.paged_cache_spec_tree(cache_shapes, rules, mesh),
@@ -499,7 +501,8 @@ def make_paged_serve_step(cfg: ModelConfig, run: RunConfig, mesh: Mesh, *,
                   num_blocks=num_blocks, chunk=chunk, slots=slots,
                   paged_kernel=paged_kernel, emit=emit,
                   kv_pool_in_place_layers=in_place_layers,
-                  kv_pool_layers=pool_layers, moe_counters=moe_counters),
+                  kv_pool_layers=pool_layers, latent_layers=latent_layers,
+                  moe_counters=moe_counters),
     )
 
 

@@ -19,7 +19,8 @@ import pytest
 from repro.configs.base import SHAPES, RunConfig, ShardingConfig
 from repro.engine import Engine, Request
 from repro.engine.teacher_forcing import teacher_forced_logits
-from repro.kernels.paged_attention import (paged_latent_attention,
+from repro.kernels.paged_attention import (latent_kernel_counts,
+                                           paged_latent_attention,
                                            paged_latent_attention_ref)
 from repro.launch.mesh import make_local_mesh
 from repro.models import model as model_lib
@@ -41,17 +42,44 @@ TINY = dict(FULL, name="tiny-mla", hidden_size=64, intermediate_size=96,
             num_experts_per_tok=3, published={"n_routed_experts": 8})
 
 
-def _latent_case(rng, dtype, *, B=3, C=4, H=4, r=32, dr=16, bs=4, M=5):
+# Rows of the kernel tests, (start, n_valid) each, with the table entries
+# past a row's resident blocks set to -1 where `unallocated` names the row.
+# "m5": M = 5 below P, so P clamps to M and a row is one grid step. "m34":
+# bs 16, so P = 16 over 34 entries, three grid steps a row, the last of two
+# entries; its rows are a prefill chunk whose 32 blocks end on a step
+# boundary, a decode row one block past it, a row with no live block, a
+# short decode row whose table is -1 past its 3 blocks, a first chunk, and
+# a chunk that fills all 34 entries. "m20": two grid steps a row, the last
+# of four entries, between idle rows: the call's first live step is not
+# its first grid step, and no live step follows its last row with tokens.
+LATENT_CASES = {
+    "m5": dict(bs=4, M=5, starts=[0, 9, 6], n_valid=[4, 3, 1],
+               unallocated=[2]),
+    "m34": dict(bs=16, M=34, starts=[500, 516, 0, 40, 0, 540],
+                n_valid=[4, 1, 0, 1, 4, 4], unallocated=[1, 3]),
+    "m20": dict(bs=16, M=20, starts=[0, 0, 0, 300, 0],
+                n_valid=[0, 2, 0, 4, 0], unallocated=[1]),
+}
+# (grid steps, live grid steps, pool rows DMA'd) of one kernel call, by hand
+LATENT_COUNTS = {"m5": (3, 3, 24), "m34": (18, 10, 1648),
+                 "m20": (10, 3, 320)}
+
+
+def _latent_case(rng, dtype, case="m5", *, C=4, H=4, r=32, dr=16):
     """Random absorbed queries, a pool holding random latent rows at the
-    rows' block-table positions, and the rows' (start, n)."""
-    lanes = 128
+    rows' block-table positions (drawn out of order from the pool), and
+    the rows' (start, n)."""
+    spec = LATENT_CASES[case]
+    bs, M = spec["bs"], spec["M"]
+    B, lanes = len(spec["starts"]), 128
     N = B * M + 2
     pool = np.zeros((N, bs, lanes), np.float32)
     pool[:, :, :r + dr] = rng.normal(size=(N, bs, r + dr))
     tables = rng.permutation(N)[:B * M].reshape(B, M).astype(np.int32)
-    tables[2, 3:] = -1                      # row 2 holds 3 blocks
-    starts = np.array([0, 9, 6], np.int32)  # prefill, mid, decode
-    n_valid = np.array([4, 3, 1], np.int32)
+    starts = np.array(spec["starts"], np.int32)
+    n_valid = np.array(spec["n_valid"], np.int32)
+    for b in spec["unallocated"]:
+        tables[b, -(-(starts[b] + n_valid[b]) // bs):] = -1
     q = np.zeros((B, C, H, lanes), np.float32)
     q[..., :r + dr] = rng.normal(size=(B, C, H, r + dr))
     cast = lambda a: jnp.asarray(a, dtype)
@@ -83,17 +111,21 @@ def _unabsorbed(q, pool, tables, starts, n_valid, *, block_size, v_lanes,
     return out
 
 
-@pytest.mark.parametrize("kernel", ["ref", "pallas"])
-def test_latent_kernel_matches_unabsorbed_attention(kernel):
+@pytest.mark.parametrize("kernel,case", [("ref", "m5"), ("pallas", "m5"),
+                                         ("pallas", "m34"), ("pallas", "m20")],
+                         ids=["ref", "pallas", "pallas-m34", "pallas-m20"])
+def test_latent_kernel_matches_unabsorbed_attention(kernel, case):
     """Absorbed through the latent pool equals attention as published, in
-    float32 to 2e-5 (float32 rounding of sums over 48 lanes and 13 keys
-    reads ~4e-7); the same inputs rounded to bf16 read ~9e-3, past 2e-3."""
+    float32 to 2e-5 (float32 rounding of sums over 48 lanes and up to 544
+    keys reads ~1e-6); the same inputs rounded to bf16 read ~9e-3, past
+    2e-3."""
     rng = np.random.default_rng(0)
     H, r, dn = 4, 32, 8
+    B = len(LATENT_CASES[case]["starts"])
     w_uk = rng.normal(size=(r, H, dn)) / math.sqrt(r)
     w_uv = rng.normal(size=(r, H, 8)) / math.sqrt(r)
-    q_nope = rng.normal(size=(3, 4, H, dn))
-    args, kw = _latent_case(rng, jnp.float32, H=H, r=r)
+    q_nope = rng.normal(size=(B, 4, H, dn))
+    args, kw = _latent_case(rng, jnp.float32, case, H=H, r=r)
     q = np.asarray(args[0]).copy()
     q[..., :r] = np.einsum("bchn,rhn->bchr", q_nope, w_uk)
     scale = 0.3
@@ -123,6 +155,32 @@ def test_latent_pallas_kernel_matches_ref_in_bf16():
     np.testing.assert_allclose(np.asarray(got, np.float32)[valid],
                                np.asarray(want, np.float32)[valid],
                                atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_pallas_kernel_matches_ref(case, dtype, tol):
+    """The kernel against ref.py on every case's valid columns; a row with
+    no live block comes back as zeros."""
+    args, kw = _latent_case(np.random.default_rng(2), dtype, case)
+    got = np.asarray(paged_latent_attention(*args, scale=0.2, **kw),
+                     np.float32)
+    want = np.asarray(paged_latent_attention_ref(*args, scale=0.2, **kw),
+                      np.float32)
+    n_valid = np.asarray(args[4])
+    valid = np.arange(4)[None, :] < n_valid[:, None]
+    np.testing.assert_allclose(got[valid], want[valid], atol=tol, rtol=tol)
+    assert not got[n_valid == 0].any()
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_CASES))
+def test_latent_kernel_counts_match_hand_counts(case):
+    spec = LATENT_CASES[case]
+    assert latent_kernel_counts(
+        spec["starts"], spec["n_valid"], block_size=spec["bs"],
+        max_blocks=spec["M"]) == LATENT_COUNTS[case]
 
 
 def test_latent_pool_write_drops_invalid_columns():
@@ -200,7 +258,15 @@ def test_paged_engine_matches_float32_reference():
             eng.submit(Request(rid, p, max_new_tokens=10))
         done = eng.run_until_drained()
         assert eng.preempt_count > 0
-        assert eng.metrics()["kv_pool_layers"] == 3
+        m = eng.metrics()
+        assert m["kv_pool_layers"] == 3
+        # 3 latent layers x 3 rows x one grid step (P clamps to M = 16)
+        # for each step of 3 x 4 token slots; a live step fetches a block
+        # of 4 rows at least
+        assert m["latent_kv_steps"] == 9 * m["step_token_slots"] // 12
+        assert 0 < m["latent_kv_steps_live"] <= m["latent_kv_steps"]
+        assert m["latent_rows_fetched"] % 4 == 0
+        assert m["latent_rows_fetched"] >= 4 * m["latent_kv_steps_live"]
         seqs = [list(p) + list(r.out_tokens[:-1])
                 for p, r in zip(prompts, sorted(done, key=lambda r: r.rid))]
         got = teacher_forced_logits(eng, seqs, [len(p) for p in prompts])

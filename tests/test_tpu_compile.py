@@ -3,9 +3,11 @@
 Interpret mode never checks the Mosaic lowering rules (block-shape tiling,
 VMEM limits), so these tests compile the kernel for a *described* v5e chip
 with the TPU compiler installed alongside JAX — no chip attached. They cover
-decode (C=1) and the chunk width the serving launcher uses. The paged step
-itself compiles at the benchmark's widths (fewer layers, a smaller pool), to
-pin which pools the TPU compiler lets the step write in place.
+decode (C=1) and the chunk width the serving launcher uses; the latent
+kernel's block-table DMAs compile at DeepSeek-V2-Lite's decode-4k shapes.
+The paged step itself compiles at the benchmark's widths (fewer layers, a
+smaller pool), to pin which pools the TPU compiler lets the step write in
+place.
 
 The topology is described only inside the module-scoped fixture below, never
 at import time: the TPU library may be loaded by one process at a time, and
@@ -67,6 +69,32 @@ def test_paged_kernel_compiles_for_v5e_at_llama32_1b_widths(one_chip, chunk):
         *xs, block_size=BLOCK_SIZE, interpret=False))
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_kernel_compiles_for_v5e_at_decode_4k_shapes(one_chip,
+                                                            monkeypatch):
+    """The latent kernel alone at DeepSeek-V2-Lite's decode-4k shapes: 24
+    rows of 8 columns, 16 heads, 640 lanes, 288 table entries over 6,912
+    pool blocks in bf16. Its block-table DMAs and double buffer lower
+    through Mosaic and fit VMEM: one Mosaic call, with no temporaries."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    B, C, H, lanes, M = 24, DEFAULT_CHUNK, 16, 640, 288
+    assert C == 8
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    args = (shape((B, C, H, lanes), jnp.bfloat16),
+            shape((B * M, BLOCK_SIZE, lanes), jnp.bfloat16),
+            shape((B, M), jnp.int32), shape((B,), jnp.int32),
+            shape((B,), jnp.int32))
+    compiled = jax.jit(lambda *xs: ops.paged_latent_attention(
+        *xs, block_size=BLOCK_SIZE, v_lanes=512, scale=0.1147)
+    ).lower(*args).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "paged_latent_attention" in calls[0]
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 STEP_LAYERS, STEP_BLOCKS = 2, 256
